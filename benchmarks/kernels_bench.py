@@ -24,11 +24,4 @@ def run(full: bool = False):
     timed(lambda: ops.gmm(xg, wg).block_until_ready())
     _, us = timed(lambda: ops.gmm(xg, wg).block_until_ready(), repeats=3)
     rows.append(Row("kernel/grouped_matmul/4x128", us, "interpret=cpu"))
-
-    xc = jnp.asarray(rng.randn(1, 18, 18, 24), jnp.float32)
-    wc = jnp.asarray(rng.randn(3, 3, 24, 32) * 0.1, jnp.float32)
-    timed(lambda: ops.conv2d(xc, wc).block_until_ready())
-    _, us = timed(lambda: ops.conv2d(xc, wc).block_until_ready(),
-                  repeats=3)
-    rows.append(Row("kernel/im2win_conv/18x18x24", us, "interpret=cpu"))
     return rows

@@ -9,6 +9,10 @@ fleet is brought up twice against one cache directory, cold (empty
 cache — every worker builds its search tables) then warm (pure disk
 hits), and per-worker start-up seconds are reported for both.
 
+This process never starts a JAX backend: the workers and the
+single-process baseline each run in a child process of their own, so on
+a TPU host every chip is free for whichever of them needs it.
+
     python -m benchmarks.replica_bench --smoke            # CI: 2 workers
     python -m benchmarks.replica_bench --full --replicas 4
     python -m benchmarks.replica_bench --smoke --ledger BENCH_serve.json \
@@ -23,10 +27,13 @@ seconds budget ``python -m benchmarks.run`` holds to.
 from __future__ import annotations
 
 import argparse
+import multiprocessing
 import statistics
 import tempfile
+from concurrent.futures import ProcessPoolExecutor
 
 from repro.core import memo
+from repro.launch.device import expected_platform
 from repro.launch.replica import WorkerConfig, serve_replicas
 from repro.launch.serve_cnn import poisson_arrivals, serve_dynamic
 
@@ -46,7 +53,7 @@ def bench_config(cache_dir: str) -> WorkerConfig:
     return WorkerConfig(net=NET, array=ARRAY, grid=GRID, layers=LAYERS,
                         groups=GROUPS, max_batch=MAX_BATCH,
                         max_delay_ms=MAX_DELAY_MS, warmup=1,
-                        cache_dir=cache_dir)
+                        cache_dir=cache_dir, platform=expected_platform())
 
 
 def bench_trace(full: bool):
@@ -62,6 +69,15 @@ def single_process_baseline(trace, cache_dir: str):
     mapping = _build_mapping(bench_config(cache_dir))
     return serve_dynamic(mapping, trace, max_batch=MAX_BATCH,
                          max_delay_ms=MAX_DELAY_MS, warmup=1)
+
+
+def baseline_in_child(trace, cache_dir: str):
+    """:func:`single_process_baseline` in a spawned child process, which
+    owns the device while it runs and releases it when it exits."""
+    ctx = multiprocessing.get_context("spawn")
+    with ProcessPoolExecutor(max_workers=1, mp_context=ctx) as pool:
+        return pool.submit(single_process_baseline, trace, cache_dir
+                           ).result()
 
 
 def replica_run(trace, cache_dir: str, n_replicas: int):
@@ -82,7 +98,7 @@ def run(full: bool = False, n_replicas: int = 2):
     with tempfile.TemporaryDirectory(prefix="replica-bench-") as cache:
         cold = replica_run(trace, cache, n_replicas)
         warm = replica_run(trace, cache, n_replicas)
-        single = single_process_baseline(trace, cache)
+        single = baseline_in_child(trace, cache)
         scaling = warm.images_per_s / max(single.images_per_s, 1e-12)
         rows.append(Row(
             f"replica/{NET}/single",

@@ -173,15 +173,14 @@ def _macro_step(p_blk: jnp.ndarray, w_blk: jnp.ndarray,
     """
     if macro_mesh_fits(mesh, p_blk.shape[0], w_blk.shape[1],
                        batch=p_blk.shape[1]):
-        from jax.experimental.shard_map import shard_map
         p_spec, w_spec, o_spec = macro_pass_specs(mesh)
 
         def local(p, w):
             part = _macro_grid(p, w).sum(0)          # local rows
             return jax.lax.psum(part, "row")         # cross-device rows
 
-        return shard_map(local, mesh=mesh, in_specs=(p_spec, w_spec),
-                         out_specs=o_spec)(p_blk, w_blk)
+        return jax.shard_map(local, mesh=mesh, in_specs=(p_spec, w_spec),
+                             out_specs=o_spec)(p_blk, w_blk)
     return _macro_grid(p_blk, w_blk).sum(0)
 
 

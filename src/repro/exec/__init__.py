@@ -21,8 +21,9 @@ from .memory import LayerMemory, network_memory, peak_bytes, total_bytes
 from .plan import (EXECUTORS, PASSES, LayerPlan, NetworkPlan, PlanDraft,
                    PolicyLike, compile_counts, compile_plan)
 from .remat import allowed_cuts, canonical_remat, plan_segments
-from .run import (apply_layer, donation_supported, execute_layerwise,
-                  execute_looped, execute_oracle, execute_plan)
+from .run import (REASSOC_RTOL, apply_layer, donation_supported,
+                  execute_layerwise, execute_looped, execute_oracle,
+                  execute_plan, reassoc_error)
 
 __all__ = [
     "ACTIVATIONS", "GLUE_KINDS", "GlueSpec", "EXECUTORS", "LayerMemory",
@@ -32,5 +33,6 @@ __all__ = [
     "constant_counts", "donation_supported", "execute_layerwise",
     "execute_looped", "execute_oracle", "execute_plan", "fit_spatial",
     "layernorm", "network_memory", "peak_bytes", "plan_segments",
-    "prepare_constants", "resolve_chain", "total_bytes",
+    "prepare_constants", "REASSOC_RTOL", "reassoc_error", "resolve_chain",
+    "total_bytes",
 ]

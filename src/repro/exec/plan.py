@@ -50,6 +50,8 @@ import jax
 from repro.core import memo
 from repro.core.types import GlueSpec, NetworkMapping
 from repro.cnn.mapped_net import LayerSchedule, check_steps, layer_schedule
+from repro.kernels.im2win_conv import (default_vmem_budget, sdk_conv_cycles,
+                                       sdk_refusal)
 from repro.launch.sharding import macro_mesh_fits
 from . import memory as memlib
 from . import remat as rematlib
@@ -83,6 +85,7 @@ class LayerPlan:
     vmem_budget: int = 8 * 1024 * 1024  # sdk: resolved byte budget
     act_bytes: int = 0          # memory pass: saved input activation
     weight_bytes: int = 0       # memory pass: shifted-weight prep
+    note: str = ""              # why the compiled sdk kernel was refused
 
     @property
     def mem_bytes(self) -> int:
@@ -164,6 +167,7 @@ class NetworkPlan:
 
     def describe(self) -> str:
         execs = ",".join(f"{lp.mapping.layer.name}:{lp.executor}"
+                         + (f"[{lp.note}]" if lp.note else "")
                          for lp in self.layers)
         tag = ("x".join(f"{n}={s}" for n, s in self.mesh_axes)
                if self.mesh_axes else "vmap")
@@ -201,25 +205,45 @@ def mesh_axes(mesh) -> Optional[Tuple[Tuple[str, int], ...]]:
     return tuple((str(n), int(s)) for n, s in mesh.shape.items())
 
 
-def _sdk_realizable(mapping) -> bool:
-    """sdk runs every pass and every group sequentially — it can only
-    stand in for the mapping when no macro/group parallelism is owed."""
-    from repro.kernels.im2win_conv import sdk_conv_cycles
-    return sdk_conv_cycles(mapping) == mapping.cycles
+def _why_not_sdk(mapping, batch: Optional[int] = None, *,
+                 block: str = "auto", vmem_budget: Optional[int] = None,
+                 compiled: bool = True) -> Optional[str]:
+    """Why the sdk executor cannot run ``mapping`` — None when it can.
+    The one home of that decision (auto heuristic, legality pass,
+    tuner seeds): sdk runs every pass and every group sequentially, so
+    it can only stand in for a mapping that owes no macro/group
+    parallelism; compiled (Mosaic, not interpret) it must also fit the
+    static TPU tiling rule at this batch (`sdk_refusal`)."""
+    if sdk_conv_cycles(mapping) != mapping.cycles:
+        return (f"sdk runs passes/groups sequentially in "
+                f"{sdk_conv_cycles(mapping)} grid steps, not the mapping's "
+                f"{mapping.cycles} cycles (sub-grid "
+                f"{mapping.sub_grid.r}x{mapping.sub_grid.c}, "
+                f"{mapping.group_rounds} group rounds)")
+    if compiled:
+        why = sdk_refusal(mapping, batch, block=block,
+                          vmem_budget=vmem_budget)
+        if why is not None:
+            return f"TPU tiling rule: {why}"
+    return None
 
 
-def _auto_executor(mapping, *, backend: str) -> str:
+def _auto_executor(mapping, *, backend: str, batch: Optional[int] = None,
+                   block: str = "auto",
+                   vmem_budget: Optional[int] = None) -> str:
     """Per-layer heuristic: the Pallas MXU paths on TPU — ``"matmul"``
     for op="matmul" layers (tetris_matmul / grouped_matmul own the
-    tiling), ``"sdk"`` for conv layers owing no macro/group parallelism
-    (its ``block="auto"`` tiling handles the VMEM budget per layer
-    size); the macro-parallel executor whenever a non-degenerate
-    sub-grid must be realized; otherwise the placement-batched reference
-    path (fewest ops — fastest off-accelerator)."""
+    tiling), ``"sdk"`` for conv layers it can run at this batch
+    (`_why_not_sdk`; its ``block="auto"`` tiling handles the VMEM
+    budget per layer size); the macro-parallel executor whenever a
+    non-degenerate sub-grid must be realized; otherwise the
+    placement-batched reference path (fewest ops — fastest
+    off-accelerator)."""
     if backend == "tpu":
         if getattr(mapping.layer, "op", "conv") == "matmul":
             return "matmul"
-        if _sdk_realizable(mapping):
+        if _why_not_sdk(mapping, batch, block=block,
+                        vmem_budget=vmem_budget) is None:
             return "sdk"
     if mapping.sub_grid.p > 1 or mapping.group_rounds < mapping.group:
         return "mapped"
@@ -227,7 +251,9 @@ def _auto_executor(mapping, *, backend: str) -> str:
 
 
 def _resolve_policy(policy: PolicyLike, net: NetworkMapping, *,
-                    backend: str) -> Tuple[str, ...]:
+                    backend: str, batch: Optional[int] = None,
+                    block: str = "auto",
+                    vmem_budget: Optional[int] = None) -> Tuple[str, ...]:
     if callable(policy):
         per_layer = [policy(m) for m in net.layers]
     elif isinstance(policy, str):
@@ -241,7 +267,8 @@ def _resolve_policy(policy: PolicyLike, net: NetworkMapping, *,
     out = []
     for name, m in zip(per_layer, net.layers):
         if name == "auto":
-            name = _auto_executor(m, backend=backend)
+            name = _auto_executor(m, backend=backend, batch=batch,
+                                  block=block, vmem_budget=vmem_budget)
         if name not in EXECUTORS:
             raise ValueError(f"unknown executor {name!r} "
                              f"(expected one of {EXECUTORS} or 'auto')")
@@ -272,6 +299,7 @@ class PlanDraft:
     remat: object                   # canonical spec (exec.remat)
     # pass products
     use_mesh: Optional[Tuple[bool, ...]] = None        # resolve_executors
+    notes: Optional[Tuple[str, ...]] = None            # resolve_executors
     glue: Optional[Tuple[GlueSpec, ...]] = None        # check_glue
     carries: Optional[Tuple[int, ...]] = None          # check_glue
     mem: Optional[Tuple[memlib.LayerMemory, ...]] = None  # estimate_memory
@@ -295,16 +323,22 @@ def pass_validate(d: PlanDraft) -> PlanDraft:
 
 
 def pass_resolve_executors(d: PlanDraft) -> PlanDraft:
-    """Executor legality per layer + the sharding decision."""
-    use = []
+    """Executor legality per layer + the sharding decision.  An explicit
+    ``"sdk"`` that `_why_not_sdk` refuses raises here; compiled
+    (non-interpret) plans note the refusal on every conv layer either
+    way, so describe() says why a layer is not on sdk."""
+    use, notes = [], []
     for m, ex in zip(d.net.layers, d.execs):
         lay = m.layer
-        if ex == "sdk" and not _sdk_realizable(m):
-            raise ValueError(
-                f"{lay.name}: executor 'sdk' runs passes/groups "
-                f"sequentially and cannot realize sub-grid "
-                f"{m.sub_grid.r}x{m.sub_grid.c} / {m.group_rounds} group "
-                f"rounds — use 'mapped'")
+        why = _why_not_sdk(m, d.batch, block=d.block,
+                           vmem_budget=d.vmem_budget,
+                           compiled=not d.interpret)
+        if ex == "sdk" and why is not None:
+            raise ValueError(f"{lay.name}: executor 'sdk' refused: {why}"
+                             f" — use 'mapped'")
+        noted = (why is not None and not d.interpret
+                 and getattr(lay, "op", "conv") == "conv")
+        notes.append(f"sdk refused: {why}" if noted else "")
         if ex == "matmul" and getattr(lay, "op", "conv") != "matmul":
             raise ValueError(
                 f"{lay.name}: executor 'matmul' requires op='matmul' "
@@ -312,7 +346,7 @@ def pass_resolve_executors(d: PlanDraft) -> PlanDraft:
         use.append(ex == "mapped"
                    and macro_mesh_fits(d.mesh, m.sub_grid.r, m.sub_grid.c,
                                        batch=d.batch))
-    return replace(d, use_mesh=tuple(use))
+    return replace(d, use_mesh=tuple(use), notes=tuple(notes))
 
 
 def pass_check_glue(d: PlanDraft) -> PlanDraft:
@@ -399,10 +433,11 @@ def _freeze(d: PlanDraft) -> NetworkPlan:
         LayerPlan(mapping=m, executor=ex, schedule=sch, glue=g,
                   carry_c=c, use_mesh=um, interpret=d.interpret,
                   block=d.block, vmem_budget=d.vmem_budget,
-                  act_bytes=mm.act_bytes, weight_bytes=mm.weight_bytes)
-        for m, ex, sch, g, c, um, mm in zip(
+                  act_bytes=mm.act_bytes, weight_bytes=mm.weight_bytes,
+                  note=note)
+        for m, ex, sch, g, c, um, mm, note in zip(
             d.net.layers, d.execs, d.schedules, d.glue, d.carries,
-            d.use_mesh, d.mem))
+            d.use_mesh, d.mem, d.notes))
     return NetworkPlan(net=d.net, layers=layers,
                        mesh_axes=mesh_axes(d.mesh), batch=d.batch,
                        chained=d.chained, lookahead=d.lookahead,
@@ -524,7 +559,6 @@ def compile_plan(net: NetworkMapping, *,
     in memory and, when a disk cache is configured, across processes —
     keyed on (net, resolved policy, mesh shape, batch, flags, remat).
     """
-    from repro.kernels.im2win_conv import default_vmem_budget
     if not net.layers:
         raise ValueError(f"{net.name}: cannot plan an empty network")
     if interpret is None:
@@ -556,7 +590,8 @@ def compile_plan(net: NetworkMapping, *,
         vmem_budget = default_vmem_budget()
     remat_spec = rematlib.canonical_remat(remat)
     execs = _resolve_policy(executor_policy, net,
-                            backend=jax.default_backend())
+                            backend=jax.default_backend(), batch=batch,
+                            block=block, vmem_budget=vmem_budget)
     key = (net, execs, mesh_axes(mesh), batch, chained, interpret, block,
            vmem_budget, lookahead, remat_spec)
 

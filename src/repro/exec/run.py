@@ -321,6 +321,26 @@ def execute_layerwise(plan: NetworkPlan, kernels: Sequence[jnp.ndarray],
                                   mesh=mesh)
 
 
+#: The agreement two executions of the same sums in different orders
+#: hold to (DESIGN.md §7): ``reassoc_error(a, b) <= REASSOC_RTOL``.
+#: Sharded vs single-device programs and rematerialized vs stored
+#: backward passes compute identical terms, but XLA fuses and orders
+#: their f32 accumulations per program, so they agree to rounding, not
+#: bit for bit.  A lost or doubled partial sum is O(1) off.
+REASSOC_RTOL = 1e-5
+
+
+def reassoc_error(a, b) -> float:
+    """``max|a - b| / max|b|``: the error of ``a`` against ``b`` relative
+    to ``b``'s largest magnitude (0 when both are all zero)."""
+    import numpy as np
+    a = np.asarray(a, np.float64)
+    b = np.asarray(b, np.float64)
+    scale = float(np.max(np.abs(b))) if b.size else 0.0
+    err = float(np.max(np.abs(a - b))) if b.size else 0.0
+    return err / scale if scale else err
+
+
 def execute_oracle(plan: NetworkPlan, kernels: Sequence[jnp.ndarray],
                    x: jnp.ndarray, *, activation=None) -> jnp.ndarray:
     """`lax.conv_general_dilated` composed over the SAME compiled chain

@@ -1,33 +1,33 @@
-"""im2win / SDK convolution — the paper's parallel window executed as a
-Pallas kernel (DESIGN.md §2 table).
+"""SDK convolution — the paper's parallel window executed as a Pallas
+kernel (DESIGN.md §2 table).
 
-Two entry points:
+:func:`sdk_conv` consumes a :class:`LayerMapping` directly.  Per
+(group, tile) one ``pallas_call`` whose grid is ``(AR_c, AC_c,
+n_windows)`` — the grid size IS the tile's computing-cycle count (ceil
+form): every grid step is one parallel-window load of one
+``ic_t x oc_t`` array pass.  Marginal / border windows execute as
+border-clamped reads of the regular window shape (overlap-recompute,
+Alg 4's hardware analogue).  It therefore executes the *same* mapping
+as the reference executor (cnn/cim_conv.py) and is cross-checked
+against it in tests/test_sdk_conv.py.
 
-* :func:`im2win_conv` — mapping-free NHWC path: picks its own square-
-  inclined window (Alg 3) and runs stride-1 VALID convolution.
-* :func:`sdk_conv` — mapping-*driven* NCHW path: consumes a
-  :class:`LayerMapping` directly.  Per (group, tile) one ``pallas_call``
-  whose grid is ``(AR_c, AC_c, n_windows)`` — the grid size IS the
-  tile's computing-cycle count (ceil form): every grid step is one
-  parallel-window load of one ``ic_t x oc_t`` array pass.  Marginal /
-  border windows execute as border-clamped reads of the regular window
-  shape (overlap-recompute, Alg 4's hardware analogue).  It therefore
-  executes the *same* mapping as the reference executor
-  (cnn/cim_conv.py) and is cross-checked against it in
-  tests/test_sdk_conv.py.
+Each step covers a (py x px) tile of output positions (the 'kernel
+computations inside the parallel window', Fig 9a) against the full
+kernel stack, computed as k_h*k_w shift-matmuls on the MXU — the
+shifted-and-duplicated kernel matrix of Fig 5 realised as shifted
+*reads* instead of duplicated *weights* (VMEM holds one kernel copy;
+the crossbar had to duplicate).
 
-One grid step == one parallel-window load == one computing cycle: the
-grid size IS the paper's cycle count for the layer.  Each step covers a
-(th x tw) tile of output positions (the 'kernel computations inside the
-parallel window', Fig 9a) against the full kernel stack, computed as
-k_h*k_w shift-matmuls on the MXU — the shifted-and-duplicated kernel
-matrix of Fig 5 realised as shifted *reads* instead of duplicated
-*weights* (VMEM holds one kernel copy; the crossbar had to duplicate).
-
-The window tile (th, tw) should come from the square-inclined rule
-(Alg 3): for fixed th*tw outputs the input patch (th+K-1)(tw+K-1) is
-minimal at th==tw.  Border windows are clamped (overlap-recompute), the
-marginal-window analogue; the step count matches the ceil form.
+**Layout inside the kernel.**  The TPU tiles the last two dimensions of
+every VMEM buffer in (8 sublane x 128 lane) blocks, and its compiler
+refuses a dynamic slice along them that it cannot prove tile-aligned.
+The window origin is a runtime value, so the kernel keeps both spatial
+axes *major*: the feature map enters as ``(AR_c, H, W, Bp, ic_t)`` and
+the output leaves as ``(AR_c, AC_c, o_h, o_w, Bp, oc_t)`` — batch on
+sublanes (padded to ``Bp``, a multiple of 8, with zero rows), channels
+on lanes.  Every block's last two dimensions are whole array
+dimensions, which the tiling rule always accepts; window slices, window
+DMAs and output stores index only the major (H, W) axes.
 """
 from __future__ import annotations
 
@@ -42,13 +42,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.tetris import factor_pairs_square_first
 from repro.core.types import LayerMapping
 
 #: Fallback ``block="auto"`` VMEM budget (bytes) when the environment
 #: does not override it.
 DEFAULT_VMEM_BUDGET = 8 * 1024 * 1024
 _VMEM_ENV_VAR = "REPRO_SDK_VMEM_BUDGET"
+
+#: f32 VMEM tile (sublanes, lanes) of the TPU vector memory.
+_SUBLANES, _LANES = 8, 128
+#: Most scoped VMEM one sdk pallas_call may ask the compiler for: the
+#: v5e TensorCore has 128 MiB, and the compiler keeps some for itself.
+VMEM_LIMIT_CAP = 96 * 1024 * 1024
 
 
 def default_vmem_budget() -> int:
@@ -73,76 +78,6 @@ def default_vmem_budget() -> int:
                          f"(unset it for the {DEFAULT_VMEM_BUDGET}-byte "
                          f"default)")
     return budget
-
-
-def select_window(o_h: int, o_w: int, k: int, c: int, oc: int,
-                  vmem_budget: int = 4 * 1024 * 1024,
-                  dtype_bytes: int = 4) -> Tuple[int, int]:
-    """Square-inclined (th, tw) output tile per window (Alg 3 on TPU)."""
-    best = (min(o_h, 8), min(o_w, 8))
-    for target in (4096, 1024, 256, 64, 16, 4):
-        for a, b in factor_pairs_square_first(target):
-            th, tw = min(a, o_h), min(b, o_w)
-            patch = (th + k - 1) * (tw + k - 1) * c
-            ws = (patch + th * tw * oc) * dtype_bytes + k * k * c * oc \
-                * dtype_bytes
-            if ws <= vmem_budget:
-                return th, tw
-    return best
-
-
-def _conv_kernel(x_ref, w_ref, o_ref, *, k_h, k_w, th, tw, o_h, o_w):
-    i = pl.program_id(1)
-    j = pl.program_id(2)
-    y0 = jnp.minimum(i * th, o_h - th)
-    x0 = jnp.minimum(j * tw, o_w - tw)
-    # leading batch index as a unit slice: interpret-mode load/store
-    # discharge rejects bare int indices mixed with dynamic slices
-    win = pl.load(x_ref, (pl.ds(0, 1), pl.ds(y0, th + k_h - 1),
-                          pl.ds(x0, tw + k_w - 1), slice(None)))[0]
-    c = win.shape[-1]
-    oc = w_ref.shape[-1]
-    acc = jnp.zeros((th * tw, oc), jnp.float32)
-    for dy in range(k_h):            # unrolled shift-matmuls (MXU passes)
-        for dx in range(k_w):
-            patch = win[dy:dy + th, dx:dx + tw, :].reshape(th * tw, c)
-            acc += jnp.dot(patch, w_ref[dy, dx],
-                           preferred_element_type=jnp.float32)
-    pl.store(o_ref, (pl.ds(0, 1), pl.ds(y0, th), pl.ds(x0, tw),
-                     slice(None)),
-             acc.reshape(1, th, tw, oc).astype(o_ref.dtype))
-
-
-def im2win_conv(x: jnp.ndarray, w: jnp.ndarray, *,
-                window: Optional[Tuple[int, int]] = None,
-                interpret: bool = False) -> jnp.ndarray:
-    """x (B, H, W, C) pre-padded; w (kh, kw, C, O); stride 1 VALID."""
-    b, h, ww, c = x.shape
-    k_h, k_w, c2, oc = w.shape
-    assert c == c2
-    o_h, o_w = h - k_h + 1, ww - k_w + 1
-    th, tw = window or select_window(o_h, o_w, max(k_h, k_w), c, oc)
-    th, tw = min(th, o_h), min(tw, o_w)
-    grid = (b, pl.cdiv(o_h, th), pl.cdiv(o_w, tw))
-
-    return pl.pallas_call(
-        functools.partial(_conv_kernel, k_h=k_h, k_w=k_w, th=th, tw=tw,
-                          o_h=o_h, o_w=o_w),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, h, ww, c), lambda bi, i, j: (bi, 0, 0, 0)),
-            pl.BlockSpec((k_h, k_w, c, oc), lambda bi, i, j: (0, 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, o_h, o_w, oc),
-                               lambda bi, i, j: (bi, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, o_h, o_w, oc), x.dtype),
-        interpret=interpret,
-    )(x, w)
-
-
-def n_cycles(o_h: int, o_w: int, th: int, tw: int, batch: int = 1) -> int:
-    """Grid steps == the mapping's computing-cycle count (ceil form)."""
-    return batch * pl.cdiv(o_h, th) * pl.cdiv(o_w, tw)
 
 
 # ---------------------------------------------------------------------------
@@ -178,87 +113,108 @@ def _window_origin(wi, *, step_y, step_x, nx, lim_y, lim_x):
     return y0, x0
 
 
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _vmem_bytes(*shape: int) -> int:
+    """f32 bytes a VMEM buffer of ``shape`` occupies in the (8, 128)
+    tiled layout: the last two dimensions round up to whole tiles."""
+    *major, sub, lane = shape
+    return (4 * math.prod(major) * _round_up(sub, _SUBLANES)
+            * _round_up(lane, _LANES))
+
+
+def _strided(v, start: int, n: int, s: int, axis: int):
+    """``n`` entries of major axis ``axis`` from ``start`` at stride ``s``.
+    A strided slice lowers to a gather the TPU compiler refuses, so
+    stride > 1 concatenates unit slices instead."""
+    if s == 1:
+        return jax.lax.slice_in_dim(v, start, start + n, axis=axis)
+    return jnp.concatenate(
+        [jax.lax.slice_in_dim(v, start + i * s, start + i * s + 1, axis=axis)
+         for i in range(n)], axis=axis)
+
+
 def _window_matmuls(win, w_ref, *, s, k_h, k_w, py, px):
     """The window's k_h*k_w unrolled shift-matmuls (MXU passes): win
-    (b, ic_t, pw_h, pw_w) x kernel block -> (b, oc_t, py, px) f32.
-    Shared by the whole-array and window-blocked kernels so the two
-    tilings cannot drift."""
-    b, oc_t = win.shape[0], w_ref.shape[3]
-    acc = jnp.zeros((b * py * px, oc_t), jnp.float32)
+    (pw_h, pw_w, Bp, ic_t) x the (ic_t x oc_t) kernel block ->
+    (py, px, Bp, oc_t) f32.  The shifts slice only the major (H, W)
+    axes; merging them into the sublane axis is tile-aligned because Bp
+    is a multiple of 8.  Shared by the whole-array and window-blocked
+    kernels so the two tilings cannot drift."""
+    bp, ic_t = win.shape[2], win.shape[3]
+    oc_t = w_ref.shape[-1]
+    acc = jnp.zeros((py * px * bp, oc_t), jnp.float32)
     for dy in range(k_h):
         for dx in range(k_w):
-            patch = win[:, :, dy:dy + (py - 1) * s + 1:s,
-                        dx:dx + (px - 1) * s + 1:s]
-            patch = patch.transpose(0, 2, 3, 1).reshape(b * py * px, -1)
-            acc += jnp.dot(patch, w_ref[dy, dx],
+            patch = _strided(_strided(win, dy, py, s, 0), dx, px, s, 1)
+            acc += jnp.dot(patch.reshape(py * px * bp, ic_t),
+                           w_ref[0, 0, dy, dx],
                            preferred_element_type=jnp.float32)
-    return acc.reshape(b, py, px, oc_t).transpose(0, 3, 1, 2)
+    return acc.reshape(py, px, bp, oc_t)
 
 
 def _sdk_kernel(x_ref, w_ref, o_ref, *, s, k_h, k_w, pw_h, pw_w, py, px,
                 step_y, step_x, nx, lim_y, lim_x):
-    """One grid step == one window load of one (ic_t x oc_t) array pass."""
+    """One grid step == one window load of one (ic_t x oc_t) array pass.
+    The (ci, oi) output block stays resident across the window axis;
+    every output position lies in some window, and clamped border
+    windows rewrite identical values."""
     wi = pl.program_id(2)
     y0, x0 = _window_origin(wi, step_y=step_y, step_x=step_x, nx=nx,
                             lim_y=lim_y, lim_x=lim_x)
-
-    @pl.when(wi == 0)
-    def _init():                     # o block is revisited across windows
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    win = x_ref[:, :, pl.ds(y0, pw_h), pl.ds(x0, pw_w)]
+    win = x_ref[0, pl.ds(y0, pw_h), pl.ds(x0, pw_w)]
     vals = _window_matmuls(win, w_ref, s=s, k_h=k_h, k_w=k_w, py=py, px=px)
-    o_ref[0, :, :, pl.ds(y0 // s, py), pl.ds(x0 // s, px)] = \
+    o_ref[0, 0, pl.ds(y0 // s, py), pl.ds(x0 // s, px)] = \
         vals.astype(o_ref.dtype)
 
 
 def _sdk_kernel_blocked(x_hbm, w_ref, o_hbm, xwin, ovals, in_sem, out_sem,
                         *, s, k_h, k_w, pw_h, pw_w, py, px, step_y, step_x,
-                        ac_c, nw, nx, lim_y, lim_x, ic_t, oc_t):
+                        ac_c, nw, nx, lim_y, lim_x):
     """Window-blocked variant of :func:`_sdk_kernel`: x and the output
     stay in HBM (``pl.ANY``); each grid step DMAs exactly one window
-    patch (b, ic_t, pw_h, pw_w) into VMEM scratch and one output tile
-    (b, oc_t, py, px) back out.  VMEM per step is the window working set
-    — independent of the feature-map size, so big Inception / DenseNet
-    layers fit where whole-array blocks would not.  Window origins are
-    border-clamped to the stride grid, which BlockSpec index maps cannot
-    express (blocks overlap); the DMA path is the general form.
+    patch (pw_h, pw_w, Bp, ic_t) into VMEM scratch and one output tile
+    (py, px, Bp, oc_t) back out.  VMEM per step is the window working
+    set — independent of the feature-map size, so big Inception /
+    DenseNet layers fit where whole-array blocks would not.  Window
+    origins are border-clamped to the stride grid, which BlockSpec index
+    maps cannot express (blocks overlap); the DMA path is the general
+    form.
 
     The DMAs are **double-buffered** (two scratch slots + paired
     semaphores, slot = flat step parity): step t prefetches window
     patch t+1 into the idle slot before waiting on its own patch, so the
     next load overlaps this step's MXU shift-matmuls; the output-tile
     store is likewise left in flight and only drained when its slot is
-    about to be reused (t+2) or the grid ends.  The grid — and therefore
-    the steps==cycles contract — is unchanged: pipelining shortens the
-    step, it does not add or remove steps."""
+    about to be reused (t+2) or the grid ends.  The pipeline spans the
+    flat step order, so every grid axis is ``"arbitrary"`` (sequential).
+    The grid — and therefore the steps==cycles contract — is unchanged:
+    pipelining shortens the step, it does not add or remove steps."""
     ci = pl.program_id(0)
     oi = pl.program_id(1)
     wi = pl.program_id(2)
     total = pl.num_programs(0) * ac_c * nw
     t = (ci * ac_c + oi) * nw + wi          # flat sequential step
 
+    def origin(step):
+        return _window_origin(step % nw, step_y=step_y, step_x=step_x,
+                              nx=nx, lim_y=lim_y, lim_x=lim_x)
+
     def in_copy(step, slot):
         """Async copy of window patch `step` into scratch slot `slot`."""
-        ci_s = step // (ac_c * nw)
-        wi_s = step % nw
-        y0, x0 = _window_origin(wi_s, step_y=step_y, step_x=step_x,
-                                nx=nx, lim_y=lim_y, lim_x=lim_x)
+        y0, x0 = origin(step)
         return pltpu.make_async_copy(
-            x_hbm.at[:, pl.ds(ci_s * ic_t, ic_t), pl.ds(y0, pw_h),
-                     pl.ds(x0, pw_w)],
+            x_hbm.at[step // (ac_c * nw), pl.ds(y0, pw_h), pl.ds(x0, pw_w)],
             xwin.at[slot], in_sem.at[slot])
 
     def out_copy(step, slot):
         """Async copy of output tile `step` out of scratch slot `slot`."""
-        ci_s = step // (ac_c * nw)
-        oi_s = step % (ac_c * nw) // nw
-        wi_s = step % nw
-        y0, x0 = _window_origin(wi_s, step_y=step_y, step_x=step_x,
-                                nx=nx, lim_y=lim_y, lim_x=lim_x)
+        y0, x0 = origin(step)
         return pltpu.make_async_copy(
             ovals.at[slot],
-            o_hbm.at[ci_s, :, pl.ds(oi_s * oc_t, oc_t),
+            o_hbm.at[step // (ac_c * nw), step % (ac_c * nw) // nw,
                      pl.ds(y0 // s, py), pl.ds(x0 // s, px)],
             out_sem.at[slot])
 
@@ -290,11 +246,69 @@ def _sdk_kernel_blocked(x_hbm, w_ref, o_hbm, xwin, ovals, in_sem, out_sem,
         out_copy(t, t % 2).wait()
 
 
-def _vmem_bytes_whole(b, ic_t, oc_t, layer) -> int:
-    """f32 VMEM working set of one whole-array-block grid step."""
-    return 4 * (b * ic_t * layer.i_h * layer.i_w
-                + layer.k_h * layer.k_w * ic_t * oc_t
-                + b * oc_t * layer.o_h * layer.o_w)
+def _tile_vmem_bytes(mode: str, layer, tile, ic_t: int, oc_t: int,
+                     bp: int) -> int:
+    """VMEM one grid step of ``mode`` ("whole" / "window") holds for a
+    tile at padded batch ``bp``: pipelined blocks twice (Pallas double
+    buffers them), DMA scratch slots, and the window's working values
+    (patch + accumulator)."""
+    w = tile.window
+    s = layer.stride
+    py = (w.pw_h - layer.k_h) // s + 1
+    px = (w.pw_w - layer.k_w) // s + 1
+    kernel = _vmem_bytes(layer.k_h * layer.k_w, ic_t, oc_t)
+    win = _vmem_bytes(w.pw_h, w.pw_w, bp, ic_t)
+    out = _vmem_bytes(py, px, bp, oc_t)
+    working = win + _vmem_bytes(py * px * bp, ic_t) + out
+    if mode == "whole":
+        return (2 * (_vmem_bytes(layer.i_h, layer.i_w, bp, ic_t) + kernel
+                     + _vmem_bytes(layer.o_h, layer.o_w, bp, oc_t))
+                + working)
+    return 2 * kernel + 2 * win + 2 * out + working
+
+
+def _tile_mode(block: str, layer, tile, ic_t: int, oc_t: int, bp: int,
+               vmem_budget: int) -> str:
+    """The tiling a tile runs in: ``block``, or for "auto" the whole-array
+    blocks when their working set fits ``vmem_budget``, else windows."""
+    if block != "auto":
+        return block
+    whole = _tile_vmem_bytes("whole", layer, tile, ic_t, oc_t, bp)
+    return "whole" if whole <= vmem_budget else "window"
+
+
+def sdk_tilings(mapping: LayerMapping, batch: Optional[int], *,
+                block: str = "auto",
+                vmem_budget: Optional[int] = None) -> Tuple[str, ...]:
+    """The tiling ("whole" / "window") each tile of ``mapping`` runs in
+    at ``batch`` (``None`` — one example, padded to 8 rows)."""
+    if vmem_budget is None:
+        vmem_budget = default_vmem_budget()
+    bp = _round_up(batch or 1, _SUBLANES)
+    return tuple(_tile_mode(block, mapping.layer, tile,
+                            *_tile_passes(mapping, tile)[::2], bp,
+                            vmem_budget)
+                 for tile in mapping.tiles)
+
+
+def sdk_refusal(mapping: LayerMapping, batch: Optional[int], *,
+                block: str = "auto",
+                vmem_budget: Optional[int] = None) -> Optional[str]:
+    """Why the compiled (Mosaic) sdk kernel cannot run ``mapping`` at
+    ``batch`` — None when it can.  A static rule from the TPU tiling
+    constraints, checked before anything is traced: a tile whose
+    working set (padded to (8, 128) tiles, see :func:`_tile_vmem_bytes`)
+    exceeds :data:`VMEM_LIMIT_CAP` has no tiling the chip accepts.
+    ``batch=None`` prices one example (padded to 8 rows)."""
+    bp = _round_up(batch or 1, _SUBLANES)
+    modes = sdk_tilings(mapping, batch, block=block, vmem_budget=vmem_budget)
+    for tile, mode in zip(mapping.tiles, modes):
+        ic_t, _, oc_t, _ = _tile_passes(mapping, tile)
+        need = _tile_vmem_bytes(mode, mapping.layer, tile, ic_t, oc_t, bp)
+        if need > VMEM_LIMIT_CAP:
+            return (f"{mode} tiling needs {need / 2**20:.1f} MiB VMEM at "
+                    f"batch {bp} (cap {VMEM_LIMIT_CAP / 2**20:.0f} MiB)")
+    return None
 
 
 def sdk_conv_traced(mapping: LayerMapping, x: jnp.ndarray,
@@ -314,6 +328,7 @@ def sdk_conv_traced(mapping: LayerMapping, x: jnp.ndarray,
     layer = mapping.layer
     s = layer.stride
     b = x.shape[0]
+    bp = _round_up(b, _SUBLANES)
     o_h, o_w = layer.o_h, layer.o_w
     g = mapping.group
     ic_g, oc_g = layer.ic // g, layer.oc // g
@@ -322,6 +337,10 @@ def sdk_conv_traced(mapping: LayerMapping, x: jnp.ndarray,
                          f"{(layer.k_h, layer.k_w, ic_g, layer.oc)}")
     if block not in ("auto", "whole", "window"):
         raise ValueError(f"unknown block mode {block!r}")
+    if not interpret:
+        why = sdk_refusal(mapping, b, block=block, vmem_budget=vmem_budget)
+        if why is not None:
+            raise ValueError(f"{layer.name}: sdk kernel refused: {why}")
 
     outs = []
     for gi in range(g):
@@ -333,71 +352,82 @@ def sdk_conv_traced(mapping: LayerMapping, x: jnp.ndarray,
             kept = tile.depth
             ic_t, ar_c, oc_t, ac_c = _tile_passes(mapping, tile)
             ic_pad, oc_pad = ar_c * ic_t, ac_c * oc_t
-
+            mode = _tile_mode(block, layer, tile, ic_t, oc_t, bp,
+                              vmem_budget)
+            # The window DMAs slice HBM arrays, whose lane dimension must
+            # then be whole 128-lane tiles: pad the channels with zeros.
+            lanes = _LANES if mode == "window" else 1
+            ic_l, oc_l = _round_up(ic_t, lanes), _round_up(oc_t, lanes)
+            # channel passes lead, channels on lanes, batch (zero-padded
+            # to whole sublane tiles) on sublanes: (ar_c, H, W, Bp, ic_l)
             xt = jnp.pad(xg[:, c_base:c_base + kept],
-                         ((0, 0), (0, ic_pad - kept), (0, 0), (0, 0)))
+                         ((0, bp - b), (0, ic_pad - kept), (0, 0), (0, 0)))
+            xt = xt.reshape(bp, ar_c, ic_t, layer.i_h, layer.i_w)
+            xt = jnp.pad(xt.transpose(1, 3, 4, 0, 2),
+                         ((0, 0),) * 4 + ((0, ic_l - ic_t),))
+            # (ar_c, ac_c, k_h, k_w, ic_l, oc_l) kernel blocks
             kt = jnp.pad(kg[:, :, c_base:c_base + kept],
                          ((0, 0), (0, 0), (0, ic_pad - kept),
                           (0, oc_pad - oc_g)))
+            kt = kt.reshape(layer.k_h, layer.k_w, ar_c, ic_t, ac_c, oc_t)
+            kt = jnp.pad(kt.transpose(2, 4, 0, 1, 3, 5),
+                         ((0, 0),) * 4 + ((0, ic_l - ic_t), (0, oc_l - oc_t)))
 
             w = tile.window
             py = (w.pw_h - layer.k_h) // s + 1
             px = (w.pw_w - layer.k_w) // s + 1
             step_y, step_x, ny, nx, lim_y, lim_x = _tile_grid(layer, tile)
-
-            mode = block
-            if mode == "auto":
-                mode = ("window"
-                        if _vmem_bytes_whole(b, ic_t, oc_t, layer)
-                        > vmem_budget else "whole")
+            geom = dict(s=s, k_h=layer.k_h, k_w=layer.k_w, pw_h=w.pw_h,
+                        pw_w=w.pw_w, py=py, px=px, step_y=step_y,
+                        step_x=step_x, nx=nx, lim_y=lim_y, lim_x=lim_x)
+            need = _tile_vmem_bytes(mode, layer, tile, ic_t, oc_t, bp)
+            params = pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",) * 3,
+                vmem_limit_bytes=min(VMEM_LIMIT_CAP,
+                                     max(32 * 2**20, 2 * need)))
+            w_spec = pl.BlockSpec(
+                (1, 1, layer.k_h, layer.k_w, ic_l, oc_l),
+                lambda ci, oi, wi: (ci, oi, 0, 0, 0, 0))
+            out_shape = jax.ShapeDtypeStruct(
+                (ar_c, ac_c, o_h, o_w, bp, oc_l), jnp.float32)
             if mode == "window":
                 res = pl.pallas_call(
-                    functools.partial(
-                        _sdk_kernel_blocked, s=s, k_h=layer.k_h,
-                        k_w=layer.k_w, pw_h=w.pw_h, pw_w=w.pw_w,
-                        py=py, px=px, step_y=step_y, step_x=step_x,
-                        ac_c=ac_c, nw=ny * nx, nx=nx,
-                        lim_y=lim_y, lim_x=lim_x, ic_t=ic_t, oc_t=oc_t),
+                    functools.partial(_sdk_kernel_blocked, ac_c=ac_c,
+                                      nw=ny * nx, **geom),
                     grid=(ar_c, ac_c, ny * nx),
-                    in_specs=[
-                        pl.BlockSpec(memory_space=pl.ANY),
-                        pl.BlockSpec((layer.k_h, layer.k_w, ic_t, oc_t),
-                                     lambda ci, oi, wi: (0, 0, ci, oi)),
-                    ],
-                    out_specs=pl.BlockSpec(memory_space=pl.ANY),
-                    out_shape=jax.ShapeDtypeStruct(
-                        (ar_c, b, oc_pad, o_h, o_w), jnp.float32),
+                    in_specs=[pl.BlockSpec(memory_space=pltpu.HBM), w_spec],
+                    out_specs=pl.BlockSpec(memory_space=pltpu.HBM),
+                    out_shape=out_shape,
                     scratch_shapes=[       # two slots: double-buffered DMA
-                        pltpu.VMEM((2, b, ic_t, w.pw_h, w.pw_w),
+                        pltpu.VMEM((2, w.pw_h, w.pw_w, bp, ic_l),
                                    jnp.float32),
-                        pltpu.VMEM((2, b, oc_t, py, px), jnp.float32),
+                        pltpu.VMEM((2, py, px, bp, oc_l), jnp.float32),
                         pltpu.SemaphoreType.DMA((2,)),
                         pltpu.SemaphoreType.DMA((2,)),
                     ],
+                    compiler_params=params,
                     interpret=interpret,
                 )(xt, kt)
             else:
                 res = pl.pallas_call(
-                    functools.partial(
-                        _sdk_kernel, s=s, k_h=layer.k_h, k_w=layer.k_w,
-                        pw_h=w.pw_h, pw_w=w.pw_w, py=py, px=px,
-                        step_y=step_y, step_x=step_x, nx=nx,
-                        lim_y=lim_y, lim_x=lim_x),
+                    functools.partial(_sdk_kernel, **geom),
                     grid=(ar_c, ac_c, ny * nx),
                     in_specs=[
-                        pl.BlockSpec((b, ic_t, layer.i_h, layer.i_w),
-                                     lambda ci, oi, wi: (0, ci, 0, 0)),
-                        pl.BlockSpec((layer.k_h, layer.k_w, ic_t, oc_t),
-                                     lambda ci, oi, wi: (0, 0, ci, oi)),
+                        pl.BlockSpec(
+                            (1, layer.i_h, layer.i_w, bp, ic_t),
+                            lambda ci, oi, wi: (ci, 0, 0, 0, 0)),
+                        w_spec,
                     ],
                     out_specs=pl.BlockSpec(
-                        (1, b, oc_t, o_h, o_w),
-                        lambda ci, oi, wi: (ci, 0, oi, 0, 0)),
-                    out_shape=jax.ShapeDtypeStruct(
-                        (ar_c, b, oc_pad, o_h, o_w), jnp.float32),
+                        (1, 1, o_h, o_w, bp, oc_t),
+                        lambda ci, oi, wi: (ci, oi, 0, 0, 0, 0)),
+                    out_shape=out_shape,
+                    compiler_params=params,
                     interpret=interpret,
                 )(xt, kt)
-            acc = acc + res.sum(axis=0)[:, :oc_g]
+            # shift-and-add over the channel passes, back to NCHW
+            res = res[..., :oc_t].sum(axis=0).transpose(3, 0, 4, 1, 2)
+            acc = acc + res.reshape(bp, oc_pad, o_h, o_w)[:b, :oc_g]
             # the tile's pruned trailing channels are skipped, not
             # shifted into the next tile's range
             c_base += kept + tile.pruned_channels

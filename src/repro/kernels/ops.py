@@ -1,8 +1,10 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On this CPU container the kernels run with interpret=True (the Pallas
-interpreter executes the kernel bodies in Python) — TPU is the target.
-``INTERPRET`` flips globally; callers can override per call.
+Interpret mode (the Pallas interpreter runs the kernel bodies in Python)
+is decided per call from the platform the call runs on: compiled Mosaic
+kernels on a TPU, the interpreter anywhere else.  Nothing is decided at
+import, so importing this module never starts a JAX backend.  Callers
+can still force either mode per call.
 """
 from __future__ import annotations
 
@@ -13,39 +15,49 @@ import jax
 
 from .flash_attention import flash_attention
 from .grouped_matmul import grouped_matmul
-from .im2win_conv import im2win_conv
 from .tetris_matmul import tetris_matmul
 
-INTERPRET = jax.default_backend() == "cpu"
+
+def _interpret(x, interpret: Optional[bool]) -> bool:
+    """``interpret``, or when None: whether the platform operand ``x``
+    lives on (a concrete array's device, else the default backend) is
+    not a TPU."""
+    if interpret is not None:
+        return interpret
+    try:
+        platform = next(iter(x.devices())).platform
+    except (AttributeError, TypeError, StopIteration):
+        platform = jax.default_backend()
+    return platform != "tpu"
 
 
 @partial(jax.jit, static_argnames=("block", "interpret"))
+def _matmul(x, w, block, interpret):
+    return tetris_matmul(x, w, block=block, interpret=interpret)
+
+
 def matmul(x, w, block: Optional[Tuple[int, int, int]] = None,
            interpret: Optional[bool] = None):
-    return tetris_matmul(x, w, block=block,
-                         interpret=INTERPRET if interpret is None
-                         else interpret)
+    return _matmul(x, w, block=block, interpret=_interpret(x, interpret))
 
 
 @partial(jax.jit, static_argnames=("bm", "bf", "interpret"))
+def _gmm(x, w, bm, bf, interpret):
+    return grouped_matmul(x, w, bm=bm, bf=bf, interpret=interpret)
+
+
 def gmm(x, w, bm: Optional[int] = None, bf: Optional[int] = None,
         interpret: Optional[bool] = None):
-    return grouped_matmul(x, w, bm=bm, bf=bf,
-                          interpret=INTERPRET if interpret is None
-                          else interpret)
-
-
-@partial(jax.jit, static_argnames=("window", "interpret"))
-def conv2d(x, w, window: Optional[Tuple[int, int]] = None,
-           interpret: Optional[bool] = None):
-    return im2win_conv(x, w, window=window,
-                       interpret=INTERPRET if interpret is None
-                       else interpret)
+    return _gmm(x, w, bm=bm, bf=bf, interpret=_interpret(x, interpret))
 
 
 @partial(jax.jit, static_argnames=("causal", "q_offset", "interpret"))
+def _attention(q, k, v, causal, q_offset, interpret):
+    return flash_attention(q, k, v, causal=causal, q_offset=q_offset,
+                           interpret=interpret)
+
+
 def attention(q, k, v, causal: bool = True, q_offset: int = 0,
               interpret: Optional[bool] = None):
-    return flash_attention(q, k, v, causal=causal, q_offset=q_offset,
-                           interpret=INTERPRET if interpret is None
-                           else interpret)
+    return _attention(q, k, v, causal=causal, q_offset=q_offset,
+                      interpret=_interpret(q, interpret))

@@ -16,14 +16,6 @@ def grouped_matmul_ref(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
                       preferred_element_type=jnp.float32).astype(x.dtype)
 
 
-def conv2d_ref(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
-    """x (B, H, W, C) pre-padded, w (kh, kw, C, O), stride 1, VALID."""
-    return jax.lax.conv_general_dilated(
-        x, w, (1, 1), "VALID",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"),
-        preferred_element_type=jnp.float32).astype(x.dtype)
-
-
 def ssd_intra_chunk_ref(x, dt, a_log, b, c) -> jnp.ndarray:
     """Intra-chunk SSD (no inter-chunk state): x (B,L,H,P); dt (B,L,H);
     a_log (H,); b,c (B,L,H,N).  y[i] = sum_{j<=i} C_i.B_j exp(dA(j,i]) x_j dt_j."""

@@ -419,7 +419,8 @@ class WorkItem:
 # Message heads on the shared worker->router result channel.  Tuples,
 # not classes: they must pickle cheaply across process boundaries and
 # stay greppable in both transports.
-MSG_READY = "ready"        # (MSG_READY, wid, startup_s, table_misses, disk_hits)
+MSG_READY = "ready"        # (MSG_READY, wid, startup_s, table_misses,
+                           #  disk_hits[, device])
 MSG_HEARTBEAT = "hb"       # (MSG_HEARTBEAT, wid, now_s)
 MSG_DONE = "done"          # (MSG_DONE, wid, tier, ((seq, rows, delay_s), ...), exec_s)
 MSG_DYING = "dying"        # (MSG_DYING, wid, reason) — flushed before death

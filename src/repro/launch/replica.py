@@ -48,7 +48,7 @@ from __future__ import annotations
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
 from repro.runtime.recovery import HeartbeatMonitor, StragglerPolicy
 
@@ -69,7 +69,11 @@ class WorkerConfig:
     compile time sane the same way fleet_bench slices densenet40);
     ``xla_host_devices`` forces that many host devices in the worker
     BEFORE jax initializes (each worker owns its mesh, so replicas can
-    shard internally too)."""
+    shard internally too).  ``platform`` is the platform the parent
+    expects every worker to come up on (`device.expected_platform`):
+    with ``"tpu"`` worker ``i`` is bound to chip ``i`` alone
+    (`device.chip_env`) before it imports JAX, and a worker that comes
+    up anywhere else refuses to serve."""
 
     net: str = "cnn8"
     array: Tuple[int, int] = (512, 512)
@@ -89,6 +93,7 @@ class WorkerConfig:
     donate: Optional[bool] = None
     heartbeat_s: float = 0.05
     xla_host_devices: Optional[int] = None
+    platform: Optional[str] = None
 
 
 # ---------------------------------------------------------------------------
@@ -107,6 +112,7 @@ class WorkerView:
     startup_s: float = 0.0
     table_misses: int = 0
     disk_hits: int = 0
+    device: str = ""            # what the worker reported it runs on
     outstanding: Dict[int, WorkItem] = field(default_factory=dict)
     outstanding_rows: int = 0
     served_requests: int = 0
@@ -170,10 +176,11 @@ class ReplicaRouter:
         return wid
 
     def on_ready(self, wid: int, startup_s: float, table_misses: int = 0,
-                 disk_hits: int = 0) -> None:
+                 disk_hits: int = 0, device: str = "") -> None:
         v = self.views[wid]
         v.startup_s = startup_s
         v.table_misses, v.disk_hits = table_misses, disk_hits
+        v.device = device
 
     def on_heartbeat(self, wid: int) -> None:
         if self.monitor is not None and self.views[wid].alive:
@@ -304,8 +311,10 @@ class ReplicaStats:
             state = "" if v.alive else " DEAD"
             strag = (f" straggler={self.stragglers[wid]}"
                      if wid in self.stragglers else "")
+            dev = f" on {v.device}" if v.device else ""
             lines.append(
-                f"  w{wid}{state}{strag}: startup {v.startup_s*1e3:.0f}ms "
+                f"  w{wid}{state}{strag}{dev}: startup "
+                f"{v.startup_s*1e3:.0f}ms "
                 f"(table_builds={v.table_misses} disk_hits={v.disk_hits}), "
                 f"{v.served_requests} requests / {v.served_rows} images "
                 f"in {v.batches} batches")
@@ -335,16 +344,47 @@ def _build_mapping(cfg: WorkerConfig):
     return map_net(cfg.net, layers, array, cfg.alg, grid, **kw)
 
 
-def _worker_main(wid: int, cfg: WorkerConfig, task_q, result_q) -> None:
-    """One replica process: build (measured), announce ready, wait for
-    GO, serve until STOP.  Runs in a fresh spawn-context interpreter —
-    env overrides land before jax initializes its backend."""
-    import os
+def worker_env(wid: int, cfg: WorkerConfig,
+               environ: Mapping[str, str]) -> Dict[str, str]:
+    """Environment overrides worker ``wid`` applies before it imports
+    JAX: on a TPU host the binding to chip ``wid`` alone
+    (`device.chip_env`), and any forced host-device count."""
+    from .device import chip_env
+    env = chip_env(wid) if cfg.platform == "tpu" else {}
     if cfg.xla_host_devices:
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "") +
+        env["XLA_FLAGS"] = (
+            environ.get("XLA_FLAGS", "") +
             f" --xla_force_host_platform_device_count="
             f"{cfg.xla_host_devices}").strip()
+    return env
+
+
+def check_worker_platform(wid: int, cfg: WorkerConfig, devices,
+                          environ: Mapping[str, str],
+                          nodes: Sequence[str] = ()) -> str:
+    """Refuse a worker that came up on another platform than its parent
+    expected (a TPU host's worker must never serve from the CPU); return
+    the ``platform:kind#ids`` tag it reports, with the device nodes it
+    holds open (``@vfio/2``; `held_device_nodes` — a bound process
+    numbers its one chip 0, the node tells the chips apart) and the chip
+    its parent bound it to (`` chip<i>``)."""
+    got = devices[0].platform
+    if cfg.platform is not None and got != cfg.platform:
+        raise RuntimeError(f"worker {wid} came up on {got}, expected "
+                           f"{cfg.platform}")
+    chip = environ.get("TPU_VISIBLE_CHIPS")
+    return (f"{got}:{devices[0].device_kind}"
+            f"#{','.join(str(d.id) for d in devices)}"
+            + (f"@{','.join(nodes)}" if nodes else "")
+            + (f" chip{chip}" if chip else ""))
+
+
+def _worker_main(wid: int, cfg: WorkerConfig, task_q, result_q) -> None:
+    """One replica process: build (measured), announce ready, wait for
+    GO, serve until STOP.  Runs in a fresh spawn-context interpreter
+    whose environment already holds its `worker_env` overrides
+    (`MpTransport.start_worker`)."""
+    import os
     import queue as queue_mod
     t_start = time.perf_counter()
     try:
@@ -355,7 +395,13 @@ def _worker_main(wid: int, cfg: WorkerConfig, task_q, result_q) -> None:
         import numpy as np
         from repro.exec import donation_supported, execute_plan
         from repro.launch import mesh as meshlib
+        from repro.launch.device import (enable_compile_cache,
+                                         held_device_nodes)
         from repro.launch.serve_cnn import _serving_kernels
+
+        enable_compile_cache()
+        device = check_worker_platform(wid, cfg, jax.devices(), os.environ,
+                                       held_device_nodes())
 
         mapping = _build_mapping(cfg)
         mesh = (meshlib.serving_mesh_for(mapping, cfg.max_batch)
@@ -380,7 +426,8 @@ def _worker_main(wid: int, cfg: WorkerConfig, task_q, result_q) -> None:
                 run_tier(t, pool[:t])
         st = memo.snapshot()
         result_q.put((MSG_READY, wid, time.perf_counter() - t_start,
-                      int(st["table_misses"]), int(st["disk_hits"])))
+                      int(st["table_misses"]), int(st["disk_hits"]),
+                      device))
     except BaseException as e:          # startup failed: say so, then die
         result_q.put((MSG_DYING, wid, f"startup: {e!r}"))
         raise
@@ -469,9 +516,11 @@ def _worker_main(wid: int, cfg: WorkerConfig, task_q, result_q) -> None:
 class MpTransport:
     """Real process-level transport: one spawn-context ``Process`` +
     task ``Queue`` per worker, one shared result ``Queue`` back.  Spawn
-    (never fork): the parent has long since initialized jax, and each
-    worker must come up with its own fresh backend (and its own
-    ``XLA_FLAGS``, applied in `_worker_main` before device init)."""
+    (never fork): each worker must come up with its own fresh backend
+    (its own chip binding and ``XLA_FLAGS``: `worker_env`, placed in the
+    environment the child is spawned with, so they hold before it
+    imports JAX).  The parent never starts a backend: a chip belongs to
+    one process at a time."""
 
     blocks = True
 
@@ -483,11 +532,22 @@ class MpTransport:
         self._task_qs: Dict[int, object] = {}
 
     def start_worker(self, wid: int, cfg: WorkerConfig) -> None:
+        import os
         q = self._ctx.Queue()
         p = self._ctx.Process(target=_worker_main,
                               args=(wid, cfg, q, self.result_q),
                               daemon=True, name=f"replica-w{wid}")
-        p.start()
+        env = worker_env(wid, cfg, os.environ)
+        saved = {k: os.environ.get(k) for k in env}
+        os.environ.update(env)          # a spawned child copies it
+        try:
+            p.start()
+        finally:
+            for k, v in saved.items():
+                if v is None:
+                    os.environ.pop(k, None)
+                else:
+                    os.environ[k] = v
         self._task_qs[wid] = q
         self._procs[wid] = p
 
@@ -559,16 +619,26 @@ def serve_replicas(trace: Sequence[Tuple[float, int]], cfg: WorkerConfig,
     if kill_worker is not None and not 0 <= kill_worker < n_replicas:
         raise ValueError(f"kill_worker={kill_worker} not in "
                          f"[0, {n_replicas})")
+    if cfg.platform is not None:
+        from .device import check_replica_count
+        check_replica_count(n_replicas, cfg.platform)
     transport = MpTransport() if transport is None else transport
 
     for wid in range(n_replicas):
         transport.start_worker(wid, cfg)
 
     # --- phase 1: wait for every worker's READY (startup measured) ---
-    ready: Dict[int, Tuple[float, int, int]] = {}
+    ready: Dict[int, Tuple] = {}
     t_limit = clock() + ready_timeout_s
     while len(ready) < n_replicas:
         msg = transport.poll(tick_s)
+        if msg is None:
+            gone = [w for w in range(n_replicas)
+                    if w not in ready and not transport.alive(w)]
+            msg = transport.poll(0.0) if gone else None
+            if gone and msg is None:    # no last word: it crashed
+                raise RuntimeError(f"replica(s) {gone} exited during "
+                                   f"startup without a message")
         if msg is None:
             if not transport.blocks:
                 sleep(tick_s)
@@ -578,7 +648,7 @@ def serve_replicas(trace: Sequence[Tuple[float, int]], cfg: WorkerConfig,
                     f"ready within {ready_timeout_s}s")
             continue
         if msg[0] == MSG_READY:
-            ready[msg[1]] = (msg[2], msg[3], msg[4])
+            ready[msg[1]] = tuple(msg[2:])
         elif msg[0] == MSG_DYING:
             raise RuntimeError(
                 f"replica {msg[1]} died during startup: {msg[2]}")
@@ -589,8 +659,8 @@ def serve_replicas(trace: Sequence[Tuple[float, int]], cfg: WorkerConfig,
                                policy=straggler,
                                clock=lambda: clock() - t0)
     router = ReplicaRouter(n_replicas, monitor=monitor)
-    for wid, (s, misses, hits) in ready.items():
-        router.on_ready(wid, s, misses, hits)
+    for wid, info in ready.items():
+        router.on_ready(wid, *info)
     for wid in range(n_replicas):
         transport.send(wid, (CTRL_GO, t0))
 

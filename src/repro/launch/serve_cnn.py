@@ -490,7 +490,11 @@ def _main_replicas(args) -> None:
     """``--replicas N``: spawn N worker processes (each mapping and
     compiling behind the shared disk cache), route a Poisson trace
     through the least-loaded dispatcher, report aggregate and
-    per-replica rates (`launch/replica.serve_replicas`)."""
+    per-replica rates (`launch/replica.serve_replicas`).  This process
+    never starts a JAX backend: on a TPU host each worker owns one chip
+    (refused when N exceeds the host's chips), and reports the device
+    it came up on."""
+    from .device import expected_platform
     from .replica import WorkerConfig, serve_replicas
     max_batch = args.max_batch or args.batch
     max_delay_ms = 2.0 if args.max_delay_ms is None else args.max_delay_ms
@@ -505,8 +509,10 @@ def _main_replicas(args) -> None:
         seed=args.seed, cache_dir=args.cache_dir, warmup=args.warmup,
         use_mesh=not args.no_mesh,
         donate=False if args.no_donate else None,
-        xla_host_devices=args.worker_devices)
+        xla_host_devices=args.worker_devices,
+        platform=expected_platform())
     print(f"{args.net} [{args.alg}] replicas={args.replicas} "
+          f"worker_platform={cfg.platform} "
           f"max_batch={max_batch} max_delay_ms={max_delay_ms} "
           f"requests={args.requests} rate={args.arrival_rate}/s")
     rs = serve_replicas(trace, cfg, args.replicas,
@@ -619,12 +625,16 @@ def main(argv=None) -> None:
     if args.cache_dir is not None:
         memo.set_disk_cache(args.cache_dir, max_bytes=args.cache_max_bytes)
 
-    if args.fleet is not None:
-        _main_fleet(args)
+    if args.replicas is not None:   # before any backend starts: the
+        _main_replicas(args)        # workers own the chips
         return
 
-    if args.replicas is not None:
-        _main_replicas(args)
+    from .device import describe_devices, enable_compile_cache
+    print(f"compile cache: {enable_compile_cache()}")
+    print(describe_devices())
+
+    if args.fleet is not None:
+        _main_fleet(args)
         return
 
     mapping, search_s = map_for_serving(

@@ -61,6 +61,9 @@ def main(argv=None) -> None:
                     help="plan trainer: microbatches per optimizer step")
     args = ap.parse_args(argv)
 
+    from repro.launch.device import describe_devices, enable_compile_cache
+    print(f"compile cache: {enable_compile_cache()}")
+    print(describe_devices())
     if args.plan_net is not None:
         return _plan_main(args)
 

@@ -114,29 +114,41 @@ class TunedConfig:
                 f"batch={self.batch})")
 
 
-def auto_policy(net, *, backend: Optional[str] = None) -> Tuple[str, ...]:
-    """The per-layer executors the ``"auto"`` heuristic resolves to —
-    the search's baseline policy and first seed."""
+def auto_policy(net, *, backend: Optional[str] = None,
+                batch: Optional[int] = None) -> Tuple[str, ...]:
+    """The per-layer executors the ``"auto"`` heuristic resolves to at
+    ``batch`` — the search's baseline policy and first seed."""
     import jax
     from repro.exec.plan import _resolve_policy
-    return _resolve_policy("auto", net,
+    return _resolve_policy("auto", net, batch=batch,
                            backend=backend or jax.default_backend())
 
 
-def policy_candidates(net, *, backend: Optional[str] = None
+def _sdk_runs(net, policy, backend: str, batch: Optional[int],
+              block: str = "auto", vmem_budget: Optional[int] = None
+              ) -> bool:
+    """Whether every sdk layer of ``policy`` is one `compile_plan`
+    accepts at ``batch`` with these sdk knobs (`_why_not_sdk`)."""
+    from repro.exec.plan import _why_not_sdk
+    return all(_why_not_sdk(m, batch, block=block, vmem_budget=vmem_budget,
+                            compiled=backend == "tpu") is None
+               for m, ex in zip(net.layers, policy) if ex == "sdk")
+
+
+def policy_candidates(net, *, backend: Optional[str] = None,
+                      batch: Optional[int] = None
                       ) -> Tuple[Tuple[str, ...], ...]:
     """Executor-policy seeds: the resolved auto heuristic, the uniform
-    policies every layer supports (sdk only on TPU and only when every
-    layer's mapping owes no macro/group parallelism), and single-layer
-    flips of the heaviest layer (largest cycle share — where a wrong
-    heuristic guess costs the most)."""
+    policies every layer supports (sdk only on TPU and only when
+    `compile_plan` accepts it on every layer at ``batch``), and
+    single-layer flips of the heaviest layer (largest cycle share —
+    where a wrong heuristic guess costs the most)."""
     import jax
-    from repro.exec.plan import _sdk_realizable
     backend = backend or jax.default_backend()
-    auto = auto_policy(net, backend=backend)
+    auto = auto_policy(net, backend=backend, batch=batch)
     n = len(net.layers)
     sdk_ok = (backend == "tpu"
-              and all(_sdk_realizable(m) for m in net.layers))
+              and _sdk_runs(net, ("sdk",) * n, backend, batch))
     # the "matmul" executor only accepts op="matmul" layers (exec/plan
     # rejects it at compile time otherwise), and like sdk it only pays
     # off on the MXU
@@ -158,9 +170,9 @@ def policy_candidates(net, *, backend: Optional[str] = None
     for name in flips:
         if name == auto[heavy]:
             continue
-        if name == "sdk" and not _sdk_realizable(net.layers[heavy]):
-            continue
         flipped = auto[:heavy] + (name,) + auto[heavy + 1:]
+        if name == "sdk" and not _sdk_runs(net, flipped, backend, batch):
+            continue
         if flipped not in out:
             out.append(flipped)
     return tuple(out)
@@ -203,16 +215,21 @@ def enumerate_space(net, *, batch: int, devices=None,
     shortlist.  ``remats`` defaults to remat-off only (serving never
     differentiates); training tuners pass e.g. ``(None, "auto")`` to
     let the search trade recompute cycles for live memory."""
+    import jax
     from repro.launch import mesh as meshlib
+    backend = backend or jax.default_backend()
     if mesh_splits is None:
         mesh_splits = meshlib.mesh_split_candidates(net, batch, devices)
     out = []
-    for policy in policy_candidates(net, backend=backend):
+    for policy in policy_candidates(net, backend=backend, batch=batch):
         has_sdk = "sdk" in policy
         for split in mesh_splits:
             for la in lookaheads:
                 for blk in (blocks if has_sdk else ("auto",)):
                     for vb in (vmem_budgets if has_sdk else (None,)):
+                        if not _sdk_runs(net, policy, backend, batch,
+                                         blk, vb):
+                            continue
                         for tiers in tiers_options:
                             for rm in remats:
                                 c = Candidate(policy=policy, lookahead=la,
@@ -234,7 +251,7 @@ def baseline_candidate(net, *, batch: int, devices=None,
     from repro.launch import mesh as meshlib
     split = meshlib.mesh_split(meshlib.serving_mesh_for(net, batch,
                                                         devices))
-    return Candidate(policy=auto_policy(net, backend=backend),
+    return Candidate(policy=auto_policy(net, backend=backend, batch=batch),
                      lookahead=1, mesh_split=split)
 
 

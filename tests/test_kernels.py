@@ -4,12 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-pytest.importorskip("hypothesis", reason="property suite needs hypothesis "
-                    "(optional test dependency, see pyproject.toml)")
-from hypothesis import given, settings, strategies as st
-
 from repro.kernels import ops, ref
-from repro.kernels.im2win_conv import n_cycles, select_window
 from repro.kernels.tetris_matmul import select_block_shape
 
 RNG = np.random.RandomState(1)
@@ -42,44 +37,10 @@ def test_grouped_matmul_sweep(gmdf, dtype):
     np.testing.assert_allclose(y, r, atol=tol, rtol=1e-2)
 
 
-@pytest.mark.parametrize("cfg", [(2, 18, 18, 24, 3, 32),
-                                 (1, 12, 12, 8, 5, 16),
-                                 (2, 9, 9, 32, 3, 64),
-                                 (1, 7, 7, 3, 3, 5)])
-def test_im2win_conv_sweep(cfg):
-    b, h, w_, c, k, o = cfg
-    x = jnp.asarray(RNG.randn(b, h, w_, c), jnp.float32)
-    kk = jnp.asarray(RNG.randn(k, k, c, o) * 0.1, jnp.float32)
-    y = np.asarray(ops.conv2d(x, kk))
-    r = np.asarray(ref.conv2d_ref(x, kk))
-    np.testing.assert_allclose(y, r, atol=1e-3, rtol=1e-3)
-
-
-@settings(max_examples=15, deadline=None)
-@given(h=st.integers(6, 20), c=st.integers(1, 16), o=st.integers(1, 16),
-       k=st.sampled_from([1, 3]))
-def test_im2win_conv_property(h, c, o, k):
-    x = jnp.asarray(RNG.randn(1, h, h, c), jnp.float32)
-    kk = jnp.asarray(RNG.randn(k, k, c, o) * 0.2, jnp.float32)
-    y = np.asarray(ops.conv2d(x, kk))
-    r = np.asarray(ref.conv2d_ref(x, kk))
-    np.testing.assert_allclose(y, r, atol=2e-3, rtol=2e-3)
-
-
 def test_select_block_shape_respects_budget():
     bm, bn, bk = select_block_shape(4096, 4096, 4096, dtype_bytes=2)
     assert (bm * bk + bk * bn) * 2 + bm * bn * 4 <= 8 * 1024 * 1024
     assert bm % 128 == 0 and bn % 128 == 0
-
-
-def test_select_window_square_inclined():
-    th, tw = select_window(32, 32, 3, 64, 64)
-    assert abs(th - tw) <= max(th, tw) // 2   # near-square (AM-GM, Alg 3)
-
-
-def test_grid_is_cycle_count():
-    assert n_cycles(16, 16, 8, 8) == 4
-    assert n_cycles(17, 16, 8, 8) == 6        # ceil form on ragged edge
 
 
 # --- flash attention -------------------------------------------------------

@@ -220,14 +220,16 @@ def test_make_macro_mesh_single_device_degenerate():
 def test_data_axis_shard_map():
     """Tentpole contract: a (data=2, row=2, col=2) mesh on 8 forced host
     devices composes batch sharding with the macro grid — forward output
-    is bit-identical to the single-device vmap path on a CNN8 slice, the
-    psum stays confined to "row", and gradients agree to float-reassoc
-    tolerance (exactly vs the lax reference at the usual 1e-3)."""
+    agrees with the single-device vmap path to the reassociation rule
+    (DESIGN.md §7) on a CNN8 slice, the psum stays confined to "row",
+    and gradients agree to float-reassoc tolerance (vs the lax reference
+    at the usual 1e-3)."""
     code = """
 import numpy as np, jax, jax.numpy as jnp
 from repro.core import ArrayConfig, MacroGrid, map_net, networks
 from repro.cnn.mapped_net import (mapped_conv2d, mapped_net_apply,
                                   reference_net_apply, zero_pruned_kernels)
+from repro.exec import REASSOC_RTOL, reassoc_error
 from repro.launch.mesh import make_macro_mesh, make_serving_mesh
 assert len(jax.devices()) == 8
 net = map_net("cnn8", networks.cnn8()[:3], ArrayConfig(64, 64),
@@ -246,7 +248,8 @@ first = net.layers[0].layer
 x = jnp.asarray(rng.randn(4, first.ic, first.i_h, first.i_w), jnp.float32)
 y_sharded = mapped_net_apply(net, ks, x, mesh=mesh)
 y_vmap = mapped_net_apply(net, ks, x)
-assert bool(jnp.all(y_sharded == y_vmap)), "forward not bit-identical"
+assert reassoc_error(y_sharded, y_vmap) <= REASSOC_RTOL, \
+    f"forward diverged: {reassoc_error(y_sharded, y_vmap)}"
 ref = reference_net_apply(net, ks, x)
 assert float(jnp.max(jnp.abs(y_sharded - ref))) < 1e-3
 

@@ -10,7 +10,8 @@ import pytest
 
 from repro.core import ArrayConfig, MacroGrid, map_net, memo, networks
 from repro.cnn.mapped_net import zero_pruned_kernels
-from repro.exec import compile_plan, execute_plan
+from repro.exec import (REASSOC_RTOL, compile_plan, execute_plan,
+                        reassoc_error)
 from repro.exec.memory import (ITEMSIZE, LayerMemory, activation_bytes,
                                peak_bytes, total_bytes, weight_prep_bytes)
 from repro.exec.remat import (allowed_cuts, canonical_remat,
@@ -197,7 +198,9 @@ def test_remat_forward_and_grads_exact_chain():
 
 def test_remat_forward_and_grads_exact_densenet_concat():
     """DenseNet prefix (concat glue + a transition cut): checkpointing
-    at the transition must not perturb forward or gradients."""
+    at the transition must not perturb the forward (bit-identical), and
+    the gradients agree to the reassociation rule (DESIGN.md §7): the
+    recomputed segment's backward is a separately fused program."""
     net = _densenet_prefix()
     ks, x = _data(net)
     flat = compile_plan(net, executor_policy="reference", batch=2)
@@ -214,7 +217,7 @@ def test_remat_forward_and_grads_exact_densenet_concat():
     g0 = jax.grad(loss(flat))(ks)
     g1 = jax.grad(loss(seg))(ks)
     for a, b in zip(g0, g1):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert reassoc_error(b, a) <= REASSOC_RTOL, reassoc_error(b, a)
 
 
 # -------------------------------------------------------------- caching

@@ -307,6 +307,19 @@ def test_ready_timeout_raises():
                     ready_timeout_s=1.0)
 
 
+class CrashedWorker(SilentWorker):
+    """Exits during startup without a word (a hard crash)."""
+
+    def step(self):
+        return False
+
+
+def test_startup_crash_raises_without_waiting_out_the_timeout():
+    with pytest.raises(RuntimeError, match="exited during startup"):
+        _fake_serve([(0.0, 1)], 2, worker_cls=CrashedWorker,
+                    ready_timeout_s=1e9)
+
+
 def test_kill_only_worker_raises_no_survivors():
     # 10 singles: two full batches drain instantly, the 2-row leftover
     # keeps the worker loaded so the kill injection actually fires.

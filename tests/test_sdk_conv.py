@@ -153,18 +153,6 @@ def test_double_buffered_window_blocked():
         atol=1e-3, rtol=1e-3)
 
 
-@pytest.mark.skipif(jax.default_backend() != "tpu",
-                    reason="compiled Pallas TPU path needs a TPU")
-def test_double_buffered_window_blocked_compiled():
-    """Same cross-check with the kernel actually compiled (Mosaic), where
-    DMA/compute overlap is real rather than interpreted."""
-    layer, m, x, k = _double_buffer_case()
-    yw = sdk_conv(m, x, k, block="window")
-    ref = reference_conv2d(layer, x, k, groups=m.group)
-    np.testing.assert_allclose(np.asarray(yw), np.asarray(ref),
-                               atol=1e-2, rtol=1e-2)
-
-
 def test_sdk_conv_no_retrace():
     """sdk_conv dispatches through a static-shape-keyed jit entry: repeat
     calls with identical (mapping, shapes, flags) must not rebuild the

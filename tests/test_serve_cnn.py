@@ -174,9 +174,10 @@ def test_serve_ragged_batch_pads_and_masks():
     """Tentpole/satellite contract on 8 forced host devices: a request
     batch of 3 does NOT divide the serving mesh's data axis (2) — the
     driver pads to the plan batch (4), serves through the mesh, masks
-    the padded row, and the 3 real outputs are bit-identical to the
-    single-device vmap plan.  Pad-and-mask isolation is total: garbage
-    in the padded row leaves the request rows bit-identical, and the
+    the padded row, and the 3 real outputs agree with the single-device
+    vmap plan to the reassociation rule (DESIGN.md §7).  Pad-and-mask
+    isolation is total: garbage in the padded row leaves the request
+    rows bit-identical, and the
     masked loss's input gradient matches the vmap plan on the request
     rows with an exactly-zero gradient on the padded row."""
     import os
@@ -186,7 +187,8 @@ def test_serve_ragged_batch_pads_and_masks():
 import numpy as np, jax, jax.numpy as jnp
 from repro.core import ArrayConfig, MacroGrid, map_net, networks
 from repro.cnn.mapped_net import zero_pruned_kernels
-from repro.exec import compile_plan, execute_plan
+from repro.exec import (REASSOC_RTOL, compile_plan, execute_plan,
+                        reassoc_error)
 from repro.launch import serve_cnn
 from repro.launch.mesh import pad_to_data_axis, serving_mesh_for
 assert len(jax.devices()) == 8
@@ -198,7 +200,7 @@ assert pad_to_data_axis(3, mesh) == 4
 s = serve_cnn.serve(net, batch=3, steps=1, warmup=1, mesh=mesh)
 assert s.request_batch == 3 and s.plan_batch == 4
 assert abs(s.padded_images_per_s / s.images_per_s - 4 / 3) < 1e-6
-# masked outputs == vmap plan on the same 3 images
+# masked outputs == vmap plan on the same 3 images (reassociation rule)
 rng = np.random.RandomState(0)
 ks = zero_pruned_kernels(net, [
     jnp.asarray(rng.randn(m.layer.k_h, m.layer.k_w,
@@ -211,11 +213,12 @@ plan = compile_plan(net, executor_policy="mapped", mesh=mesh, batch=4)
 y = execute_plan(plan, ks, x4, mesh=mesh)[:3]
 vmap_plan = compile_plan(net, executor_policy="mapped")
 y_ref = execute_plan(vmap_plan, ks, x3)
-assert bool(jnp.all(y == y_ref)), "masked sharded outputs != vmap"
+assert reassoc_error(y, y_ref) <= REASSOC_RTOL, \
+    "masked sharded outputs != vmap"
 # isolation: garbage in the padded row must not touch request rows
 x4_dirty = x4.at[3].set(7.5)
 y_dirty = execute_plan(plan, ks, x4_dirty, mesh=mesh)[:3]
-assert bool(jnp.all(y_dirty == y_ref)), "padded row leaked into outputs"
+assert bool(jnp.all(y_dirty == y)), "padded row leaked into outputs"
 # gradient isolation: masked loss -> request-row grads match the vmap
 # plan, padded-row grad exactly zero
 g4 = jax.grad(lambda xx: jnp.sum(

@@ -290,3 +290,22 @@ def test_fleet_signature_keys_platform_and_count():
     key1 = tune.tuning_key("net", fleet, 4)
     key2 = tune.tuning_key("net", ("tpu", 8), 4)
     assert key1 != key2
+
+
+def test_tpu_seeds_only_sdk_policies_compile_plan_accepts():
+    """The tuner asks the plan compiler's own sdk predicate at its
+    batch: on a TPU the auto seed runs CNN8-3 on sdk at the serving
+    batch, and at a batch whose sdk working set the chip refuses no
+    seed or candidate puts it there (compile_plan would raise)."""
+    net = map_net("cnn8", networks.cnn8(), ArrayConfig(512, 512),
+                  "TetrisG-SDK", MacroGrid(), groups=(1, 2, 4))
+    assert tune.auto_policy(net, backend="tpu", batch=64)[1] == "sdk"
+    huge = 4096
+    assert all(p[1] != "sdk"
+               for p in tune.policy_candidates(net, backend="tpu",
+                                               batch=huge))
+    cands = tune.enumerate_space(net, batch=huge, backend="tpu",
+                                 mesh_splits=[None], lookaheads=(1,))
+    assert cands and all(c.policy[1] != "sdk" for c in cands)
+    for p in tune.policy_candidates(net, backend="tpu", batch=64):
+        compile_plan(net, executor_policy=p, batch=64, interpret=False)
