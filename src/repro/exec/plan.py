@@ -132,14 +132,6 @@ class NetworkPlan:
         return sum(lp.schedule.steps for lp in self.layers)
 
     @property
-    def host_dispatches(self) -> int:
-        """jit program launches per forward through the fused entries
-        (`execute_plan` for chains, `execute_layerwise` for layer sets):
-        always one — the per-layer loop (`execute_looped` /
-        `apply_layer`) launched ``len(self.layers)``."""
-        return 1
-
-    @property
     def spans(self) -> Tuple[Tuple[int, int], ...]:
         """The segment ranges dispatch iterates — one whole-net span
         when the segment pass did not run / remat is off."""
@@ -175,8 +167,7 @@ class NetworkPlan:
         return (f"plan[{self.net.name}] layers={len(self.layers)} "
                 f"steps={self.total_steps} mesh={tag} "
                 f"lookahead={self.lookahead} "
-                f"peak_mem={self.peak_bytes / 1e6:.1f}MB{seg} "
-                f"dispatches/forward={self.host_dispatches} ({execs})")
+                f"peak_mem={self.peak_bytes / 1e6:.1f}MB{seg} ({execs})")
 
     def describe_memory(self) -> str:
         """Per-layer memory-pass estimates, one line per layer, with

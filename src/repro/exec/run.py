@@ -37,6 +37,8 @@ from jax import lax
 
 from repro.cnn.cim_conv import cim_conv2d_jit, cim_conv2d_traced
 from repro.cnn.mapped_net import mapped_conv2d_jit, mapped_conv2d_traced
+from repro.launch.device import compile_stats
+from repro.launch.tracing import GLUE, span
 from .glue import (ACTIVATIONS, attention_stage, center_crop, fit_spatial,
                    layernorm)
 from .plan import LayerPlan, NetworkPlan, mesh_axes
@@ -101,7 +103,11 @@ def _forward(plan: NetworkPlan, kernels, x: jnp.ndarray, mesh,
     shifted-weight blocks.  Deliberately NOT threaded through the
     lookahead fence below: the fence bounds *in-program* kernel-side
     prep, and a pre-materialized buffer has none — XLA hoisting a plain
-    program input to the start is free."""
+    program input to the start is free.
+
+    Each layer runs in its named scope ``L{i:02d}.{name}``; what lies
+    outside its executor runs under ``glue``, the executor's own work
+    under its phases (launch/tracing.PHASES)."""
     lay0 = plan.layers[0].mapping.layer
     if x.shape[1] != lay0.ic:
         raise ValueError(f"{lay0.name}: input has {x.shape[1]} channels,"
@@ -126,50 +132,55 @@ def _forward(plan: NetworkPlan, kernels, x: jnp.ndarray, mesh,
             lp = plan.layers[i]
             lay = lp.mapping.layer
             spec = lp.glue
-            xp = fit_spatial(x, lay.i_h, lay.i_w)
-            if spec.save:           # residual base: the pre-norm input
-                saved.append(xp)
-            xin = layernorm(xp) if spec.pre == "layernorm" else xp
-            y = conv(lp, xin, seg_kernels[i - s]) if conv is not None \
-                else _layer_conv(
-                    lp, xin, seg_kernels[i - s], mesh, jitted=jitted,
-                    prepared=None if seg_consts is None
-                    else seg_consts[i - s])
-            if spec.act != "none":
-                y = ACTIVATIONS[spec.act](y)
-            elif activation is not None and not explicit:
-                y = activation(y)
-            if spec.post == "attention":
-                # the opaque stage between mapped qkv and o projections —
-                # glue, not a mapped layer, so cycle accounting is
-                # untouched
-                y = attention_stage(y, spec.heads, spec.causal,
-                                    interpret=lp.interpret)
-            if spec.kind == "concat":
-                skip = center_crop(xp, y.shape[-2], y.shape[-1])
-                x = jnp.concatenate([skip, y], axis=1)
-            elif spec.kind == "residual":
-                # channel match was validated at compile time; saved
-                # bases are deliberately NOT threaded through the
-                # lookahead fence — they are live carries, not
-                # kernel-side prep
-                x = saved.pop() + y
-            else:                   # "chain" / "last"
-                x = y
-            # cross-layer pipeline depth (plan.lookahead, a compile_plan
-            # argument since ISSUE 6): kernels of layers beyond
-            # ``i + 1 + lookahead`` stay fenced behind this carry, so
-            # that many layers of kernel-side prep (weight-matrix
-            # blocks, gather indices) may overlap the current psum
-            # drain while the live working set stays bounded.  The
-            # window is clamped to the segment (``j < e``): pipelining
-            # never reaches across a checkpoint boundary
-            j = i + 1 + plan.lookahead
-            if fused and j < e:
-                # bounded pipelining (module docstring): layers past the
-                # lookahead window cannot start until this carry exists
-                x, *rest = _fence((x, *seg_kernels[j - s:]))
-                seg_kernels[j - s:] = rest
+            with jax.named_scope(f"L{i:02d}.{lay.name}"):
+                with jax.named_scope(GLUE):
+                    xp = fit_spatial(x, lay.i_h, lay.i_w)
+                    if spec.save:   # residual base: the pre-norm input
+                        saved.append(xp)
+                    xin = layernorm(xp) if spec.pre == "layernorm" else xp
+                y = conv(lp, xin, seg_kernels[i - s]) if conv is not None \
+                    else _layer_conv(
+                        lp, xin, seg_kernels[i - s], mesh, jitted=jitted,
+                        prepared=None if seg_consts is None
+                        else seg_consts[i - s])
+                with jax.named_scope(GLUE):
+                    if spec.act != "none":
+                        y = ACTIVATIONS[spec.act](y)
+                    elif activation is not None and not explicit:
+                        y = activation(y)
+                    if spec.post == "attention":
+                        # the opaque stage between mapped qkv and o
+                        # projections — glue, not a mapped layer, so
+                        # cycle accounting is untouched
+                        y = attention_stage(y, spec.heads, spec.causal,
+                                            interpret=lp.interpret)
+                    if spec.kind == "concat":
+                        skip = center_crop(xp, y.shape[-2], y.shape[-1])
+                        x = jnp.concatenate([skip, y], axis=1)
+                    elif spec.kind == "residual":
+                        # channel match was validated at compile time;
+                        # saved bases are deliberately NOT threaded
+                        # through the lookahead fence — they are live
+                        # carries, not kernel-side prep
+                        x = saved.pop() + y
+                    else:           # "chain" / "last"
+                        x = y
+                    # cross-layer pipeline depth (plan.lookahead, a
+                    # compile_plan argument): kernels of layers beyond
+                    # ``i + 1 + lookahead`` stay fenced behind this
+                    # carry, so that many layers of kernel-side prep
+                    # (weight-matrix blocks, gather indices) may overlap
+                    # the current psum drain while the live working set
+                    # stays bounded.  The window is clamped to the
+                    # segment (``j < e``): pipelining never reaches
+                    # across a checkpoint boundary
+                    j = i + 1 + plan.lookahead
+                    if fused and j < e:
+                        # bounded pipelining (module docstring): layers
+                        # past the lookahead window cannot start until
+                        # this carry exists
+                        x, *rest = _fence((x, *seg_kernels[j - s:]))
+                        seg_kernels[j - s:] = rest
         return x
 
     kernels = list(kernels)
@@ -270,25 +281,40 @@ def execute_plan(plan: NetworkPlan, kernels: Sequence[jnp.ndarray],
     this plan's network: its pre-materialized shifted-weight blocks feed
     the mapped layers as program inputs, shared across every tier/ladder
     of the network (``prepare_constants``).
+
+    The call runs inside the host span ``repro.execute_plan`` (its
+    ``batch`` is the tier): checks, hashing the static plan, and the
+    jit dispatch.
     """
-    _check_call(plan, kernels, x, mesh)
-    consts = None
-    if constants is not None:
-        if constants.net != plan.net:
-            raise ValueError("constants were prepared for a different "
-                             "network mapping than this plan")
-        if constants.executors != plan.executors:
-            raise ValueError(
-                f"constants were prepared for executors "
-                f"{constants.executors}, plan resolved {plan.executors}")
-        if len(constants.weights) != len(plan.layers):
-            raise ValueError(f"{len(constants.weights)} constant entries "
-                             f"for {len(plan.layers)} planned layers")
-        consts = constants.weights
-    fn = _execute_jit_donated if donate and donation_supported(mesh) \
-        else _execute_jit
-    return fn(plan, tuple(kernels), x, consts, mesh=mesh,
-              activation=activation)
+    with span("execute_plan", batch=x.shape[0]):
+        _check_call(plan, kernels, x, mesh)
+        consts = None
+        if constants is not None:
+            if constants.net != plan.net:
+                raise ValueError("constants were prepared for a different "
+                                 "network mapping than this plan")
+            if constants.executors != plan.executors:
+                raise ValueError(
+                    f"constants were prepared for executors "
+                    f"{constants.executors}, plan resolved "
+                    f"{plan.executors}")
+            if len(constants.weights) != len(plan.layers):
+                raise ValueError(f"{len(constants.weights)} constant "
+                                 f"entries for {len(plan.layers)} planned "
+                                 f"layers")
+            consts = constants.weights
+        fn = _execute_jit_donated if donate and donation_supported(mesh) \
+            else _execute_jit
+        return fn(plan, tuple(kernels), x, consts, mesh=mesh,
+                  activation=activation)
+
+
+def plan_compile_stats():
+    """:func:`repro.launch.device.compile_stats` of the whole-forward
+    programs alone: what tracing, lowering and compiling (or loading)
+    every plan `execute_plan` has run cost this process."""
+    return compile_stats(_execute_jit.__name__,
+                         _execute_jit_donated.__name__)
 
 
 def execute_looped(plan: NetworkPlan, kernels: Sequence[jnp.ndarray],

@@ -100,6 +100,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((bq, d), jnp.float32),       # output accumulator
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)
 
 

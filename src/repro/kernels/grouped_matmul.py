@@ -54,4 +54,5 @@ def grouped_matmul(x: jnp.ndarray, w: jnp.ndarray, *,
                               jnp.minimum(j, last(f, bf)))),
         out_shape=jax.ShapeDtypeStruct((g, m, f), x.dtype),
         interpret=interpret,
+        name="grouped_matmul",
     )(x, w)

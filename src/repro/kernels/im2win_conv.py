@@ -43,6 +43,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.types import LayerMapping
+from repro.launch.tracing import MACRO, PLACE
 
 #: Fallback ``block="auto"`` VMEM budget (bytes) when the environment
 #: does not override it.
@@ -344,9 +345,10 @@ def sdk_conv_traced(mapping: LayerMapping, x: jnp.ndarray,
 
     outs = []
     for gi in range(g):
-        xg = x[:, gi * ic_g:(gi + 1) * ic_g]
-        kg = kernel[:, :, :, gi * oc_g:(gi + 1) * oc_g]
-        acc = jnp.zeros((b, oc_g, o_h, o_w), jnp.float32)
+        with jax.named_scope(PLACE):
+            xg = x[:, gi * ic_g:(gi + 1) * ic_g]
+            kg = kernel[:, :, :, gi * oc_g:(gi + 1) * oc_g]
+            acc = jnp.zeros((b, oc_g, o_h, o_w), jnp.float32)
         c_base = 0
         for tile in mapping.tiles:
             kept = tile.depth
@@ -358,20 +360,25 @@ def sdk_conv_traced(mapping: LayerMapping, x: jnp.ndarray,
             # then be whole 128-lane tiles: pad the channels with zeros.
             lanes = _LANES if mode == "window" else 1
             ic_l, oc_l = _round_up(ic_t, lanes), _round_up(oc_t, lanes)
-            # channel passes lead, channels on lanes, batch (zero-padded
-            # to whole sublane tiles) on sublanes: (ar_c, H, W, Bp, ic_l)
-            xt = jnp.pad(xg[:, c_base:c_base + kept],
-                         ((0, bp - b), (0, ic_pad - kept), (0, 0), (0, 0)))
-            xt = xt.reshape(bp, ar_c, ic_t, layer.i_h, layer.i_w)
-            xt = jnp.pad(xt.transpose(1, 3, 4, 0, 2),
-                         ((0, 0),) * 4 + ((0, ic_l - ic_t),))
-            # (ar_c, ac_c, k_h, k_w, ic_l, oc_l) kernel blocks
-            kt = jnp.pad(kg[:, :, c_base:c_base + kept],
-                         ((0, 0), (0, 0), (0, ic_pad - kept),
-                          (0, oc_pad - oc_g)))
-            kt = kt.reshape(layer.k_h, layer.k_w, ar_c, ic_t, ac_c, oc_t)
-            kt = jnp.pad(kt.transpose(2, 4, 0, 1, 3, 5),
-                         ((0, 0),) * 4 + ((0, ic_l - ic_t), (0, oc_l - oc_t)))
+            with jax.named_scope(PLACE):
+                # channel passes lead, channels on lanes, batch (zero-
+                # padded to whole sublane tiles) on sublanes:
+                # (ar_c, H, W, Bp, ic_l)
+                xt = jnp.pad(xg[:, c_base:c_base + kept],
+                             ((0, bp - b), (0, ic_pad - kept), (0, 0),
+                              (0, 0)))
+                xt = xt.reshape(bp, ar_c, ic_t, layer.i_h, layer.i_w)
+                xt = jnp.pad(xt.transpose(1, 3, 4, 0, 2),
+                             ((0, 0),) * 4 + ((0, ic_l - ic_t),))
+                # (ar_c, ac_c, k_h, k_w, ic_l, oc_l) kernel blocks
+                kt = jnp.pad(kg[:, :, c_base:c_base + kept],
+                             ((0, 0), (0, 0), (0, ic_pad - kept),
+                              (0, oc_pad - oc_g)))
+                kt = kt.reshape(layer.k_h, layer.k_w, ar_c, ic_t, ac_c,
+                                oc_t)
+                kt = jnp.pad(kt.transpose(2, 4, 0, 1, 3, 5),
+                             ((0, 0),) * 4 + ((0, ic_l - ic_t),
+                                              (0, oc_l - oc_t)))
 
             w = tile.window
             py = (w.pw_h - layer.k_h) // s + 1
@@ -390,50 +397,56 @@ def sdk_conv_traced(mapping: LayerMapping, x: jnp.ndarray,
                 lambda ci, oi, wi: (ci, oi, 0, 0, 0, 0))
             out_shape = jax.ShapeDtypeStruct(
                 (ar_c, ac_c, o_h, o_w, bp, oc_l), jnp.float32)
-            if mode == "window":
-                res = pl.pallas_call(
-                    functools.partial(_sdk_kernel_blocked, ac_c=ac_c,
-                                      nw=ny * nx, **geom),
-                    grid=(ar_c, ac_c, ny * nx),
-                    in_specs=[pl.BlockSpec(memory_space=pltpu.HBM), w_spec],
-                    out_specs=pl.BlockSpec(memory_space=pltpu.HBM),
-                    out_shape=out_shape,
-                    scratch_shapes=[       # two slots: double-buffered DMA
-                        pltpu.VMEM((2, w.pw_h, w.pw_w, bp, ic_l),
-                                   jnp.float32),
-                        pltpu.VMEM((2, py, px, bp, oc_l), jnp.float32),
-                        pltpu.SemaphoreType.DMA((2,)),
-                        pltpu.SemaphoreType.DMA((2,)),
-                    ],
-                    compiler_params=params,
-                    interpret=interpret,
-                )(xt, kt)
-            else:
-                res = pl.pallas_call(
-                    functools.partial(_sdk_kernel, **geom),
-                    grid=(ar_c, ac_c, ny * nx),
-                    in_specs=[
-                        pl.BlockSpec(
-                            (1, layer.i_h, layer.i_w, bp, ic_t),
-                            lambda ci, oi, wi: (ci, 0, 0, 0, 0)),
-                        w_spec,
-                    ],
-                    out_specs=pl.BlockSpec(
-                        (1, 1, o_h, o_w, bp, oc_t),
-                        lambda ci, oi, wi: (ci, oi, 0, 0, 0, 0)),
-                    out_shape=out_shape,
-                    compiler_params=params,
-                    interpret=interpret,
-                )(xt, kt)
-            # shift-and-add over the channel passes, back to NCHW
-            res = res[..., :oc_t].sum(axis=0).transpose(3, 0, 4, 1, 2)
-            acc = acc + res.reshape(bp, oc_pad, o_h, o_w)[:b, :oc_g]
+            with jax.named_scope(MACRO):
+                if mode == "window":
+                    res = pl.pallas_call(
+                        functools.partial(_sdk_kernel_blocked, ac_c=ac_c,
+                                          nw=ny * nx, **geom),
+                        grid=(ar_c, ac_c, ny * nx),
+                        in_specs=[pl.BlockSpec(memory_space=pltpu.HBM),
+                                  w_spec],
+                        out_specs=pl.BlockSpec(memory_space=pltpu.HBM),
+                        out_shape=out_shape,
+                        scratch_shapes=[       # two slots: double-buffered DMA
+                            pltpu.VMEM((2, w.pw_h, w.pw_w, bp, ic_l),
+                                       jnp.float32),
+                            pltpu.VMEM((2, py, px, bp, oc_l), jnp.float32),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SemaphoreType.DMA((2,)),
+                        ],
+                        compiler_params=params,
+                        interpret=interpret,
+                        name="sdk_conv",
+                    )(xt, kt)
+                else:
+                    res = pl.pallas_call(
+                        functools.partial(_sdk_kernel, **geom),
+                        grid=(ar_c, ac_c, ny * nx),
+                        in_specs=[
+                            pl.BlockSpec(
+                                (1, layer.i_h, layer.i_w, bp, ic_t),
+                                lambda ci, oi, wi: (ci, 0, 0, 0, 0)),
+                            w_spec,
+                        ],
+                        out_specs=pl.BlockSpec(
+                            (1, 1, o_h, o_w, bp, oc_t),
+                            lambda ci, oi, wi: (ci, oi, 0, 0, 0, 0)),
+                        out_shape=out_shape,
+                        compiler_params=params,
+                        interpret=interpret,
+                        name="sdk_conv",
+                    )(xt, kt)
+            with jax.named_scope(PLACE):
+                # shift-and-add over the channel passes, back to NCHW
+                res = res[..., :oc_t].sum(axis=0).transpose(3, 0, 4, 1, 2)
+                acc = acc + res.reshape(bp, oc_pad, o_h, o_w)[:b, :oc_g]
             # the tile's pruned trailing channels are skipped, not
             # shifted into the next tile's range
             c_base += kept + tile.pruned_channels
         outs.append(acc)
-    return jnp.concatenate(outs, axis=1).astype(
-        jnp.result_type(x, kernel))
+    with jax.named_scope(PLACE):
+        return jnp.concatenate(outs, axis=1).astype(
+            jnp.result_type(x, kernel))
 
 
 #: Host-side trace counter keyed by the static signature — retracing

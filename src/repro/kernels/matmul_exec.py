@@ -31,6 +31,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.launch.tracing import MACRO, PLACE
+
 from .grouped_matmul import grouped_matmul
 from .tetris_matmul import tetris_matmul
 
@@ -53,19 +55,26 @@ def matmul_layer_traced(mapping, x: jnp.ndarray, kernel: jnp.ndarray, *,
         raise ValueError(
             f"{layer.name}: kernel {kernel.shape} != (1, 1, {d_g}, "
             f"{layer.oc}) — grouped conv layout, G={g}")
-    tok = x[..., 0]                                     # (B, ic, M)
+    with jax.named_scope(PLACE):
+        tok = x[..., 0]                                 # (B, ic, M)
     if g == 1:
-        xm = tok.transpose(0, 2, 1).reshape(b * m, layer.ic)
-        y = tetris_matmul(xm, kernel[0, 0], interpret=interpret)
-        return y.reshape(b, m, layer.oc).transpose(0, 2, 1)[..., None]
-    # channels are group-major on both sides: ic = (g, d_g) in x,
-    # oc = (g, f_g) along the kernel's last axis
-    xg = (tok.reshape(b, g, d_g, m).transpose(1, 0, 3, 2)
-          .reshape(g, b * m, d_g))
-    wg = kernel[0, 0].reshape(d_g, g, f_g).transpose(1, 0, 2)
-    y = grouped_matmul(xg, wg, interpret=interpret)     # (g, B*M, f_g)
-    return (y.reshape(g, b, m, f_g).transpose(1, 0, 3, 2)
-            .reshape(b, layer.oc, m)[..., None])
+        with jax.named_scope(PLACE):
+            xm = tok.transpose(0, 2, 1).reshape(b * m, layer.ic)
+        with jax.named_scope(MACRO):
+            y = tetris_matmul(xm, kernel[0, 0], interpret=interpret)
+        with jax.named_scope(PLACE):
+            return y.reshape(b, m, layer.oc).transpose(0, 2, 1)[..., None]
+    with jax.named_scope(PLACE):
+        # channels are group-major on both sides: ic = (g, d_g) in x,
+        # oc = (g, f_g) along the kernel's last axis
+        xg = (tok.reshape(b, g, d_g, m).transpose(1, 0, 3, 2)
+              .reshape(g, b * m, d_g))
+        wg = kernel[0, 0].reshape(d_g, g, f_g).transpose(1, 0, 2)
+    with jax.named_scope(MACRO):
+        y = grouped_matmul(xg, wg, interpret=interpret)  # (g, B*M, f_g)
+    with jax.named_scope(PLACE):
+        return (y.reshape(g, b, m, f_g).transpose(1, 0, 3, 2)
+                .reshape(b, layer.oc, m)[..., None])
 
 
 @functools.partial(jax.jit, static_argnums=(0,),

@@ -83,6 +83,7 @@ def ssd_chunk(x: jnp.ndarray, dt: jnp.ndarray, a_log: jnp.ndarray,
             jax.ShapeDtypeStruct((B * nc, H, P, N), jnp.float32),
         ],
         interpret=interpret,
+        name="ssd_chunk",
     )(xr, dtr, a_log, br, cr)
     return (y.reshape(B, S, H, P),
             s.reshape(B, nc, H, P, N))

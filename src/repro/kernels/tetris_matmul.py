@@ -111,6 +111,7 @@ def tetris_matmul(x: jnp.ndarray, w: jnp.ndarray, *,
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="tetris_matmul",
     )(x, w)
 
 

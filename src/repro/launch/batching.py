@@ -55,6 +55,7 @@ from typing import (Callable, Deque, Dict, List, Optional, Sequence,
                     Tuple)
 
 from . import mesh as meshlib
+from .tracing import span
 
 
 @dataclass(frozen=True)
@@ -189,16 +190,17 @@ class Coalescer:
         nothing is ready yet.  ``force=True`` drains regardless of the
         delay deadline (the final flush once no further arrival can grow
         the batch); an empty queue drains to ``[]`` either way."""
-        if not self._q or not (force or self.ready(now)):
-            return []
-        batch: List[Request] = []
-        rows = 0
-        while self._q and rows + self._q[0].rows <= self.max_batch:
-            r = self._q.popleft()
-            batch.append(r)
-            rows += r.rows
-        self._rows -= rows
-        return batch
+        with span("pop"):
+            if not self._q or not (force or self.ready(now)):
+                return []
+            batch: List[Request] = []
+            rows = 0
+            while self._q and rows + self._q[0].rows <= self.max_batch:
+                r = self._q.popleft()
+                batch.append(r)
+                rows += r.rows
+            self._rows -= rows
+            return batch
 
 
 def batch_tiers(max_batch: int, mesh=None) -> Tuple[int, ...]:
@@ -267,8 +269,9 @@ class PlanLadder:
 
     def plan_for(self, rows: int):
         """``(tier, plan)`` serving a ``rows``-image coalesced batch."""
-        t = tier_for(rows, self.tiers)
-        return t, self.plans[t]
+        with span("plan_for"):
+            t = tier_for(rows, self.tiers)
+            return t, self.plans[t]
 
 
 def percentile(xs: Sequence[float], q: float) -> float:

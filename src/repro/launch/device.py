@@ -5,6 +5,11 @@ worker, `chip_smoke.py`) calls :func:`enable_compile_cache` before its
 first compile and prints :func:`describe_devices`, so each result names
 the platform, device kind and device count it came from.
 
+The compile observer listens to JAX's compile events from this module's
+import on and keeps, per jitted function, the seconds spent tracing,
+lowering and compiling and the persistent-cache hits and misses
+(:func:`compile_stats`).
+
 The helpers that bind replica workers to chips (:func:`host_tpu_chips`,
 :func:`chip_env`, :func:`expected_platform`) read only the environment
 and the host's PCI listing: the router process that calls them never
@@ -14,8 +19,10 @@ from __future__ import annotations
 
 import glob
 import os
+import re
+import threading
 from pathlib import Path
-from typing import Dict, Mapping, Optional, Tuple
+from typing import Dict, Mapping, Optional, Sequence, Tuple
 
 #: The checkout's fixed compile-cache directory (git-ignored).  Fixed,
 #: because the path is part of the cache key: a moving directory never
@@ -24,8 +31,6 @@ REPO_ROOT = Path(__file__).resolve().parents[3]
 DEFAULT_COMPILE_CACHE = REPO_ROOT / ".jax_cache"
 
 _CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
-_cache_events: Dict[str, int] = {"requests": 0, "hits": 0}
-_listening = False
 
 
 def compile_cache_dir(environ: Mapping[str, str] = os.environ
@@ -38,13 +43,6 @@ def compile_cache_dir(environ: Mapping[str, str] = os.environ
     return str(DEFAULT_COMPILE_CACHE), False
 
 
-def _count_cache_event(event: str, **_kw) -> None:
-    if event == "/jax/compilation_cache/compile_requests_use_cache":
-        _cache_events["requests"] += 1
-    elif event == "/jax/compilation_cache/cache_hits":
-        _cache_events["hits"] += 1
-
-
 def enable_compile_cache(platform: Optional[str] = None) -> Optional[str]:
     """Turn on JAX's persistent compilation cache and return its
     directory (None when it stays off).  With
@@ -54,13 +52,8 @@ def enable_compile_cache(platform: Optional[str] = None) -> Optional[str]:
     :data:`DEFAULT_COMPILE_CACHE`; the CPU, where tests and
     interpret-mode rehearsals run, keeps no cache.  On an accelerator
     every compiled program is kept: a serving ladder is many sub-second
-    compiles, each below JAX's default one-second write threshold.  Also
-    starts counting cache lookups (:func:`compile_cache_stats`)."""
-    global _listening
+    compiles, each below JAX's default one-second write threshold."""
     import jax
-    if not _listening:
-        jax.monitoring.register_event_listener(_count_cache_event)
-        _listening = True
     path, from_env = compile_cache_dir()
     if (platform or jax.default_backend()) == "cpu":
         return path if from_env else None
@@ -70,11 +63,109 @@ def enable_compile_cache(platform: Optional[str] = None) -> Optional[str]:
     return path
 
 
-def compile_cache_stats() -> Dict[str, int]:
-    """Persistent-cache ``hits`` and ``misses`` (lookups that compiled)
-    this process has seen since :func:`enable_compile_cache`."""
-    return {"hits": _cache_events["hits"],
-            "misses": _cache_events["requests"] - _cache_events["hits"]}
+# ---------------------------------------------------------------------------
+# Compile observer
+# ---------------------------------------------------------------------------
+
+#: JAX's compile phases, each reported with the jitted function's name:
+#: tracing to a jaxpr, lowering it to an MLIR module, and the backend
+#: compile (which includes a persistent-cache lookup and load).
+_PHASE_SECONDS = {"/jax/core/compile/jaxpr_trace_duration": "trace_s",
+                  "/jax/core/compile/jaxpr_to_mlir_module_duration":
+                      "lower_s",
+                  "/jax/core/compile/backend_compile_duration": "compile_s"}
+_CACHE_REQUEST = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_STAT_KEYS = ("hits", "misses", "trace_s", "lower_s", "compile_s")
+_FUN = re.compile(r"\w+\((.*)\)")
+
+
+def _fun(name: str) -> str:
+    """``_execute_jit`` from ``_execute_jit`` or ``jit(_execute_jit)``:
+    JAX names the tracing phase by the function, the others by its
+    module."""
+    m = _FUN.fullmatch(name)
+    return m.group(1) if m else name
+
+
+class _CompileObserver:
+    """Seconds of each compile phase and cache lookups, per function.
+
+    Phases nest: tracing a jitted function traces every jitted function
+    it calls, and a constant it computes eagerly compiles mid-trace.
+    Each phase's start is reported too, so only the outermost phase of
+    a thread is counted, under its own function, and inner lookups count
+    for that function: the three kinds of seconds never overlap."""
+
+    def __init__(self):
+        self.by_fun: Dict[str, Dict[str, float]] = {}
+        self._local = threading.local()
+
+    def _open(self) -> list:
+        open_ = getattr(self._local, "open", None)
+        if open_ is None:
+            open_ = self._local.open = []
+        return open_
+
+    def _row(self, fun: str) -> Dict[str, float]:
+        row = self.by_fun.get(fun)
+        if row is None:
+            row = self.by_fun[fun] = dict.fromkeys(_STAT_KEYS, 0)
+        return row
+
+    def started(self, event: str, _value, *, fun_name: str = "",
+                **_kw) -> None:
+        if event in _PHASE_SECONDS:
+            self._open().append(_fun(fun_name))
+
+    def finished(self, event: str, secs: float, *, fun_name: str = "",
+                 **_kw) -> None:
+        key = _PHASE_SECONDS.get(event)
+        if key is None:
+            return
+        open_ = self._open()
+        if open_:
+            open_.pop()
+        if not open_:
+            self._row(_fun(fun_name))[key] += secs
+
+    def counted(self, event: str, **_kw) -> None:
+        if event not in (_CACHE_REQUEST, _CACHE_HIT):
+            return
+        open_ = self._open()
+        row = self._row(open_[0] if open_ else "")
+        if event == _CACHE_HIT:
+            row["hits"] += 1
+            row["misses"] -= 1      # every hit was first a request
+        else:
+            row["misses"] += 1
+
+    def stats(self, funs: Sequence[str]) -> Dict[str, float]:
+        rows = [self.by_fun[f] for f in funs if f in self.by_fun] \
+            if funs else list(self.by_fun.values())
+        return {k: sum(r[k] for r in rows) for k in _STAT_KEYS}
+
+
+def _observe() -> _CompileObserver:
+    from jax import monitoring
+    obs = _CompileObserver()
+    monitoring.register_scalar_listener(obs.started)
+    monitoring.register_event_duration_secs_listener(obs.finished)
+    monitoring.register_event_listener(obs.counted)
+    return obs
+
+
+_observer = _observe()
+
+
+def compile_stats(*funs: str) -> Dict[str, float]:
+    """What this process has compiled since this module was imported:
+    persistent-cache ``hits`` and ``misses`` (lookups that compiled), and
+    the seconds of ``trace_s``, ``lower_s`` and ``compile_s`` (the
+    backend compile, or the load on a cache hit).  With ``funs``, only
+    the jitted functions of those names and what compiled while they
+    were traced or lowered."""
+    return _observer.stats(funs)
 
 
 def device_summary() -> Dict[str, object]:

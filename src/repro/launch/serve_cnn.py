@@ -715,7 +715,7 @@ def main(argv=None) -> None:
           f"images_per_s={s.images_per_s:.1f};"
           f"padded_images_per_s={s.padded_images_per_s:.1f};"
           f"plan_batch={s.plan_batch};"
-          f"dispatches={s.plan.host_dispatches};mesh={tag};"
+          f"mesh={tag};"
           f"search_ms={search_s*1e3:.1f};table_builds={st['table_misses']};"
           f"disk_hits={st['disk_hits']}")
 

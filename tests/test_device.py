@@ -37,11 +37,38 @@ def test_enable_compile_cache_sets_no_dir_when_env_set(monkeypatch,
         assert jax.config.jax_compilation_cache_dir == str(
             device.DEFAULT_COMPILE_CACHE)
         assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
-        assert set(device.compile_cache_stats()) == {"hits", "misses"}
+        assert set(device.compile_stats()) == {
+            "hits", "misses", "trace_s", "lower_s", "compile_s"}
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           min_s)
+
+
+def test_compile_stats_times_each_phase_of_a_fresh_jit_once():
+    """A fresh jit adds trace, lower and compile seconds, under its own
+    name too; calling it again adds nothing.  The jitted function it
+    calls is traced inside it and not counted twice."""
+    @jax.jit
+    def _inner_probe(x):
+        return x * 3
+
+    @jax.jit
+    def _outer_probe(x):
+        return _inner_probe(x) + 1
+
+    before = device.compile_stats()
+    _outer_probe(jax.numpy.arange(5.0)).block_until_ready()
+    mid = device.compile_stats()
+    own = device.compile_stats("_outer_probe")
+    for k in ("trace_s", "lower_s", "compile_s"):
+        assert mid[k] > before[k]
+        assert own[k] > 0
+    assert own["hits"] + own["misses"] >= 1
+    assert device.compile_stats("_inner_probe") == dict.fromkeys(own, 0)
+    assert own["trace_s"] <= mid["trace_s"] - before["trace_s"]
+    _outer_probe(jax.numpy.arange(5.0)).block_until_ready()
+    assert device.compile_stats() == mid
 
 
 def _fake_pci(root, devices):

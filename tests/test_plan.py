@@ -45,7 +45,6 @@ def test_compile_plan_ir_fields():
     assert plan.chained and plan.mesh_axes is None and plan.batch is None
     assert plan.executors == ("mapped",) * len(net.layers)
     assert plan.total_steps == net.total_cycles
-    assert plan.host_dispatches == 1
     for lp, m in zip(plan.layers, net.layers):
         assert lp.mapping is m
         assert lp.schedule.steps == m.cycles     # compile-time contract
@@ -53,7 +52,7 @@ def test_compile_plan_ir_fields():
         assert lp.carry_c == m.layer.ic
     assert all(lp.glue.kind == "chain" for lp in plan.layers[:-1])
     assert plan.layers[-1].glue.kind == "last"
-    assert "dispatches/forward=1" in plan.describe()
+    assert f"steps={net.total_cycles} " in plan.describe()
 
 
 def test_compile_plan_policies():
