@@ -33,11 +33,13 @@ schedule derivation the plan compiler consumes.
 
 Numerics follow cnn/cim_conv.py: window loads of one congruent shape are
 gathered and multiplied in one batch (sequential in hardware, counted as
-such); each channel super-step writes a set-semantics buffer (overlapping
-border/marginal windows recompute identical partial sums), buffers
-accumulate across channel super-steps — the shift-and-add adds of Fig 3,
-with the cross-row reduction of a super-step becoming a ``psum`` over the
-mesh "row" axis in the sharded path.
+such).  Each shape's array-pass results are summed over the channel
+super-steps — the shift-and-add adds of Fig 3, with the cross-row
+reduction of a super-step becoming a ``psum`` over the mesh "row" axis in
+the sharded path — and each tile is then placed into the output raster
+once, by one gather with a host-built index that gives every output pixel
+one window that writes it (overlapping border/marginal windows compute
+identical partial sums, so any writer will do).
 """
 from __future__ import annotations
 
@@ -48,9 +50,10 @@ from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.core.types import (LayerMapping, MacroGrid, NetworkMapping,
-                              TileMapping)
+from repro.core.types import (ConvLayerSpec, LayerMapping, MacroGrid,
+                              NetworkMapping, TileMapping)
 from repro.launch.sharding import macro_mesh_fits, macro_pass_specs
 from repro.launch.tracing import MACRO, PATCHES, PLACE, WEIGHTS
 from .cim_conv import (build_weight_matrix, gather_patches,
@@ -88,6 +91,13 @@ class LayerSchedule:
         """Executed sequential grid steps — the measured counterpart of
         ``LayerMapping.cycles``."""
         return self.group_rounds * sum(t.steps for t in self.tiles)
+
+    @property
+    def placements(self) -> int:
+        """Placement ops a forward runs for the layer: one gather per
+        tile, after its channel super-steps are summed (the group axis
+        batches the group rounds)."""
+        return len(self.tiles)
 
 
 @functools.lru_cache(maxsize=None)
@@ -234,20 +244,20 @@ def _tile_operands(mapping: LayerMapping, tile: TileMapping,
                    xc: jnp.ndarray, ks: Optional[jnp.ndarray],
                    R: int, C: int,
                    prepared: Optional[Sequence[jnp.ndarray]] = None
-                   ) -> List[dict]:
+                   ) -> List[Tuple[jnp.ndarray, jnp.ndarray]]:
     """Pass-blocked operands per congruent window shape.
 
     xc (b, g, ic_pad, H, W) and ks (k_h, k_w, ic_pad, g, oc_pad) are the
     tile's channel slice zero-padded to whole super-steps.  For each
-    shape: patches (R, sub_r, b, g, N, K) with K = ic_t*ph*pw, and
-    weights (R, C, sub_r, sub_c, g, K, npos*oc_t) — the row/oc blocking
+    shape, in `placement_groups` order: patches (R, sub_r, b, g, N, K)
+    with K = ic_t*ph*pw, and weights (R, C, sub_r, sub_c, g, K,
+    npos*oc_t) — the row/oc blocking
     of the Fig 5 shifted-and-duplicated matrix.  ``prepared`` substitutes
     pre-materialized weight blocks (`_tile_weights` order) for the
     in-trace build — the plan-constant sharing path; ``ks`` may then be
     None.
     """
     layer = mapping.layer
-    s = layer.stride
     sub = mapping.sub_grid
     ic_t, _, _, _ = mapping.tile_passes(tile)
     b, g = xc.shape[0], xc.shape[1]
@@ -262,8 +272,6 @@ def _tile_operands(mapping: LayerMapping, tile: TileMapping,
                          f"blocks for {len(groups)} window shapes")
     out = []
     for (ph, pw), origins in groups.items():
-        py = (ph - layer.k_h) // s + 1
-        px = (pw - layer.k_w) // s + 1
         K = ic_t * ph * pw
         with jax.named_scope(PATCHES):
             flat = gather_patches(xc, origins, ph, pw)  # (b,g,N,ic_pad*ph*pw)
@@ -271,10 +279,34 @@ def _tile_operands(mapping: LayerMapping, tile: TileMapping,
             p_all = flat.reshape(b, g, n, R * sub.r, K)
             p_all = p_all.transpose(3, 0, 1, 2, 4).reshape(
                 R, sub.r, b, g, n, K)
-        OY, OX = scatter_indices(origins, py, px, s)
-        out.append(dict(p_all=p_all, w_all=weights[len(out)], OY=OY, OX=OX,
-                        py=py, px=px))
+        out.append((p_all, weights[len(out)]))
     return out
+
+
+@functools.lru_cache(maxsize=None)
+def placement_index(layer: ConvLayerSpec, tile: TileMapping) -> np.ndarray:
+    """Host-built placement of one tile: for every output pixel (row-major
+    over (o_h, o_w)), its position in the tile's results flattened over
+    (shape, window, y, x) in `placement_groups` order — the last window
+    that writes the pixel.  Overlapping windows compute identical values,
+    so one writer per pixel places the tile exactly; coverage
+    (core.cycles.axis_covers) guarantees every pixel has one."""
+    s = layer.stride
+    owner = np.full(layer.o_h * layer.o_w, -1, np.int32)
+    base = 0
+    for (ph, pw), origins in placement_groups(layer, tile).items():
+        py = (ph - layer.k_h) // s + 1
+        px = (pw - layer.k_w) // s + 1
+        OY, OX = scatter_indices(origins, py, px, s)
+        pix = np.broadcast_to(OY * layer.o_w + OX,
+                              (len(origins), py, px)).ravel()
+        owner[pix] = base + np.arange(pix.size)
+        base += pix.size
+    if (owner < 0).any():
+        raise AssertionError(
+            f"{layer.name}: {int((owner < 0).sum())} output pixels have no "
+            f"window in tile {tile.window}")
+    return owner
 
 
 def prepared_layer_weights(mapping: LayerMapping, kernel: jnp.ndarray
@@ -321,13 +353,13 @@ def mapped_conv2d_traced(mapping: LayerMapping, x: jnp.ndarray,
     tile's channel range) are skipped.  ``weights`` substitutes this
     layer's pre-materialized shifted-weight blocks
     (:func:`prepared_layer_weights`) for the in-trace build — the
-    plan-constant sharing path; ``kernel`` is then only consulted for
-    the result dtype (and may be None).
+    plan-constant sharing path; ``kernel`` is then unused (and may be
+    None).
 
     Its work runs in four named scopes (launch/tracing.PHASES):
     ``weights`` (the in-trace weight build), ``patches`` (the window
-    gather), ``macro`` (the array passes) and ``place`` (placing and
-    accumulating their results)."""
+    gather), ``macro`` (the array passes) and ``place`` (summing their
+    results over the channel super-steps, then placing each tile once)."""
     layer = mapping.layer
     b = x.shape[0]
     o_h, o_w = layer.o_h, layer.o_w
@@ -340,23 +372,19 @@ def mapped_conv2d_traced(mapping: LayerMapping, x: jnp.ndarray,
                              f"{(layer.k_h, layer.k_w, ic_g, layer.oc)}")
         with jax.named_scope(WEIGHTS):
             kr = kernel.reshape(layer.k_h, layer.k_w, ic_g, g, oc_g)
-        w_dtype = kernel.dtype
     else:
         if len(weights) != len(mapping.tiles):
             raise ValueError(f"{layer.name}: {len(weights)} prepared "
                              f"weight tiles for {len(mapping.tiles)}")
         kr = None
-        w_dtype = weights[0][0].dtype
 
     # all groups are congruent: the group axis batches the gr*gc-parallel
     # groups; sequential group rounds only multiply the step count
     with jax.named_scope(PATCHES):
         xr = x.reshape(b, g, ic_g, layer.i_h, layer.i_w)
-    with jax.named_scope(PLACE):
-        out = jnp.zeros((b, g, oc_g, o_h, o_w),
-                        jnp.result_type(x.dtype, w_dtype))
 
     sub = mapping.sub_grid
+    out = None                               # (o_h*o_w, b, g, oc_g)
     c_base = 0
     for ti, tile in enumerate(mapping.tiles):
         kept = tile.depth
@@ -377,37 +405,37 @@ def mapped_conv2d_traced(mapping: LayerMapping, x: jnp.ndarray,
                                 prepared=None if weights is None
                                 else weights[ti])
 
-        with jax.named_scope(PLACE):
-            acc = jnp.zeros((b, g, oc_pad, o_h, o_w), out.dtype)
-        soc = sub.c * oc_t                   # oc columns per super-step
-        for ri in range(R):
-            # one channel super-step: set semantics within it (every
-            # window writes this step's full partial sum), accumulate
-            # across steps (shift-and-add)
-            with jax.named_scope(PLACE):
-                buf = jnp.zeros_like(acc)
+        flat = []              # per shape: (n*py*px, b, g, oc_g) results
+        for p_all, w_all in shapes:
+            cols = []
             for ci in range(C):
-                for sh in shapes:
+                # the shift-and-add: sum this oc super-step's passes over
+                # the R channel super-steps before anything is placed
+                acc = None
+                for ri in range(R):
                     with jax.named_scope(MACRO):
-                        res = _macro_step(sh["p_all"][ri],
-                                          sh["w_all"][ri, ci], mesh)
-                    py, px = sh["py"], sh["px"]
-                    n = res.shape[3]
+                        res = _macro_step(p_all[ri], w_all[ri, ci], mesh)
                     with jax.named_scope(PLACE):
-                        vals = res.reshape(sub.c, b, g, n, py, px, oc_t)
-                        vals = vals.transpose(1, 2, 0, 6, 3, 4, 5).reshape(
-                            b, g, soc, n, py, px)
-                        buf = buf.at[:, :, ci * soc:(ci + 1) * soc,
-                                     sh["OY"], sh["OX"]].set(vals)
+                        acc = res if acc is None else acc + res
+                cols.append(acc)
             with jax.named_scope(PLACE):
-                acc = acc + buf
+                # (sub_c, b, g, n, py*px*oc_t) per oc super-step -> the
+                # C super-steps side by side along oc
+                v = jnp.concatenate(
+                    [c.reshape(sub.c, b, g, -1, oc_t).transpose(3, 1, 2, 0, 4)
+                     .reshape(-1, b, g, sub.c * oc_t) for c in cols],
+                    axis=3)
+                flat.append(v[..., :oc_g])
         with jax.named_scope(PLACE):
-            out = out + acc[:, :, :oc_g]
+            placed = jnp.take(jnp.concatenate(flat, axis=0),
+                              placement_index(layer, tile), axis=0,
+                              mode="clip", unique_indices=True)
+            out = placed if out is None else out + placed
         # skip the tile's pruned trailing channels instead of shifting
         # the next tile's range onto them
         c_base += kept + tile.pruned_channels
     with jax.named_scope(PLACE):
-        return out.reshape(b, layer.oc, o_h, o_w)
+        return out.transpose(1, 2, 3, 0).reshape(b, layer.oc, o_h, o_w)
 
 
 mapped_conv2d_jit = functools.partial(

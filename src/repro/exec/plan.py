@@ -157,15 +157,26 @@ class NetworkPlan:
         once — the remat-off baseline the frontier is measured against."""
         return memlib.total_bytes(self.layer_memory)
 
+    @property
+    def placements(self) -> int:
+        """Placement ops a forward runs in its mapped layers (one per
+        tile, `LayerSchedule.placements`)."""
+        return sum(lp.schedule.placements for lp in self.layers
+                   if lp.executor == "mapped")
+
     def describe(self) -> str:
-        execs = ",".join(f"{lp.mapping.layer.name}:{lp.executor}"
-                         + (f"[{lp.note}]" if lp.note else "")
-                         for lp in self.layers)
+        execs = ",".join(
+            f"{lp.mapping.layer.name}:{lp.executor}"
+            + (f"[{lp.note}]" if lp.note else "")
+            + (f"(p={lp.schedule.placements})" if lp.executor == "mapped"
+               else "")
+            for lp in self.layers)
         tag = ("x".join(f"{n}={s}" for n, s in self.mesh_axes)
                if self.mesh_axes else "vmap")
         seg = f" segments={len(self.segments)}" if self.segments else ""
         return (f"plan[{self.net.name}] layers={len(self.layers)} "
-                f"steps={self.total_steps} mesh={tag} "
+                f"steps={self.total_steps} placements={self.placements} "
+                f"mesh={tag} "
                 f"lookahead={self.lookahead} "
                 f"peak_mem={self.peak_bytes / 1e6:.1f}MB{seg} ({execs})")
 

@@ -2,9 +2,12 @@
 equivalence against the lax.conv composition, executed grid steps ==
 analytical cycle counts, exact gradients, and the shard_map device path.
 """
+import functools
 import os
+import re
 import subprocess
 import sys
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -13,11 +16,15 @@ import pytest
 
 from repro.core import (ArrayConfig, ConvLayerSpec, MacroGrid, map_layer,
                         map_net, networks)
-from repro.cnn.cim_conv import reference_conv2d
-from repro.cnn.mapped_net import (assert_steps_match, executed_steps,
+from repro.cnn.cim_conv import (placement_groups, reference_conv2d,
+                                scatter_indices)
+from repro.cnn.mapped_net import (_macro_step, _tile_dims, _tile_operands,
+                                  assert_steps_match, executed_steps,
                                   layer_schedule, mapped_conv2d,
-                                  mapped_net_apply, network_schedule,
-                                  reference_net_apply, zero_pruned_kernels)
+                                  mapped_conv2d_jit, mapped_net_apply,
+                                  network_schedule, reference_net_apply,
+                                  zero_pruned_kernels)
+from repro.exec import REASSOC_RTOL, compile_plan, reassoc_error
 
 RNG = np.random.RandomState(11)
 
@@ -161,6 +168,175 @@ def test_mapped_gradients_match_reference():
         reference_conv2d(layer, x, kk, groups=m.group) ** 2))(k)
     np.testing.assert_allclose(np.asarray(zap(gm)), np.asarray(zap(gr)),
                                rtol=1e-4, atol=1e-4 * float(jnp.max(jnp.abs(gr))))
+
+
+# ---------------------------------------------------------------------------
+# Placement: channel super-steps summed, then each tile placed once
+# ---------------------------------------------------------------------------
+
+@functools.lru_cache(maxsize=None)
+def _bench_mapping(name):
+    """The benchmark's mapping of a network (bench/configs): TetrisG-SDK
+    on one 512x512 array, grouping factors 1, 2 or 4."""
+    return map_net(name, networks.NETWORKS[name](), ArrayConfig(512, 512),
+                   "TetrisG-SDK", MacroGrid(), groups=(1, 2, 4))
+
+
+def _bench_layer(name):
+    net = _bench_mapping("cnn8" if name.startswith("CNN8") else "densenet40")
+    return next(m for m in net.layers if m.layer.name == name)
+
+
+def _tile_blocks(m):
+    """(R, C, window shapes) of every tile: the executor's loop bounds."""
+    return [_tile_dims(m, t)[:2] + (len(placement_groups(m.layer, t)),)
+            for t in m.tiles]
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _scatter_placement(m, x, k):
+    """The former placement, kept as an oracle: each channel super-step
+    writes every shape's results into a zero buffer by a set-semantics
+    scatter, and the buffers are summed across super-steps."""
+    lay, g, b = m.layer, m.group, x.shape[0]
+    ic_g, oc_g = lay.ic // g, lay.oc // g
+    kr = k.reshape(lay.k_h, lay.k_w, ic_g, g, oc_g)
+    xr = x.reshape(b, g, ic_g, lay.i_h, lay.i_w)
+    out = jnp.zeros((b, g, oc_g, lay.o_h, lay.o_w), x.dtype)
+    sub, c_base = m.sub_grid, 0
+    for tile in m.tiles:
+        kept, oc_t = tile.depth, m.tile_passes(tile)[2]
+        R, C, ic_pad, oc_pad = _tile_dims(m, tile)
+        xc = jnp.pad(xr[:, :, c_base:c_base + kept],
+                     ((0, 0), (0, 0), (0, ic_pad - kept), (0, 0), (0, 0)))
+        ks = jnp.pad(kr[:, :, c_base:c_base + kept],
+                     ((0, 0), (0, 0), (0, ic_pad - kept), (0, 0),
+                      (0, oc_pad - oc_g)))
+        shapes = []
+        for (p_all, w_all), ((ph, pw), o) in zip(
+                _tile_operands(m, tile, xc, ks, R, C),
+                placement_groups(lay, tile).items()):
+            py = (ph - lay.k_h) // lay.stride + 1
+            px = (pw - lay.k_w) // lay.stride + 1
+            shapes.append((p_all, w_all, py, px,
+                           scatter_indices(o, py, px, lay.stride)))
+        acc = jnp.zeros((b, g, oc_pad, lay.o_h, lay.o_w), x.dtype)
+        soc = sub.c * oc_t
+        for ri in range(R):
+            buf = jnp.zeros_like(acc)
+            for ci in range(C):
+                for p_all, w_all, py, px, (OY, OX) in shapes:
+                    res = _macro_step(p_all[ri], w_all[ri, ci])
+                    n = res.shape[3]
+                    vals = res.reshape(sub.c, b, g, n, py, px, oc_t)
+                    vals = vals.transpose(1, 2, 0, 6, 3, 4, 5).reshape(
+                        b, g, soc, n, py, px)
+                    buf = buf.at[:, :, ci * soc:(ci + 1) * soc,
+                                 OY, OX].set(vals)
+            acc = acc + buf
+        out = out + acc[:, :, :oc_g]
+        c_base += kept + tile.pruned_channels
+    return out.reshape(b, lay.oc, lay.o_h, lay.o_w)
+
+
+@pytest.mark.parametrize("name,blocks", [
+    ("DN40-b3l12", [(44, 1, 1)]),           # 44 channel super-steps
+    ("DN40-b2l3", [(18, 1, 1), (1, 3, 1)]),  # a tile of 3 oc super-steps
+    ("DN40-t1", [(1, 1, 2)]),               # 1x1 kernel, 171 windows
+    ("DN40-b1l3", [(1, 1, 3), (1, 1, 2)]),  # overlapping marginal windows
+    ("CNN8-2", [(1, 1, 3)]),                # g=4, three shapes
+    ("CNN8-7", [(4, 1, 1)]),                # 1x1 output
+])
+def test_placement_extreme_schedules(name, blocks):
+    """Summing the super-steps and placing each tile once computes the
+    convolution, and the former per-super-step scatter placement to the
+    reassociation rule, on the benchmark's most extreme schedules."""
+    m = _bench_layer(name)
+    assert _tile_blocks(m) == blocks
+    if name == "DN40-t1":
+        assert layer_schedule(m).tiles[0].window_loads == 171
+    if name == "CNN8-2":
+        assert m.group == 4
+    lay = m.layer
+    x = jnp.asarray(RNG.randn(2, lay.ic, lay.i_h, lay.i_w), jnp.float32)
+    k = jnp.asarray(RNG.randn(lay.k_h, lay.k_w, lay.ic // m.group, lay.oc),
+                    jnp.float32)
+    k = zero_pruned_kernels(SimpleNamespace(layers=[m]), [k])[0]
+    with jax.default_matmul_precision("highest"):
+        y = mapped_conv2d(m, x, k)
+        old = _scatter_placement(m, x, k)
+        ref = reference_conv2d(lay, x, k, groups=m.group)
+    assert reassoc_error(y, old) <= REASSOC_RTOL, reassoc_error(y, old)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(ref), rtol=1e-4,
+                               atol=1e-4 * float(jnp.max(jnp.abs(ref))))
+
+
+def test_mapped_gradients_summed_super_steps():
+    """Gradients through the summed, once-placed tile on a layer with two
+    channel super-steps and overlapping windows: the transpose of the
+    one-writer-per-pixel gather neither drops nor double-counts."""
+    m = _bench_layer("DN40-b1l1")
+    lay = m.layer
+    (R, _, _), = _tile_blocks(m)
+    loads = sum(len(o) * ((ph - lay.k_h) // lay.stride + 1)
+                * ((pw - lay.k_w) // lay.stride + 1)
+                for (ph, pw), o in placement_groups(lay, m.tiles[0]).items())
+    assert R > 1 and loads > lay.o_h * lay.o_w         # windows overlap
+    x = jnp.asarray(RNG.randn(1, lay.ic, lay.i_h, lay.i_w), jnp.float32)
+    k = jnp.asarray(RNG.randn(lay.k_h, lay.k_w, lay.ic // m.group, lay.oc),
+                    jnp.float32)
+
+    def loss(conv):
+        return lambda xx, kk: jnp.sum(conv(xx, kk) ** 2)
+
+    with jax.default_matmul_precision("highest"):
+        gm = jax.grad(loss(lambda xx, kk: mapped_conv2d(m, xx, kk)),
+                      argnums=(0, 1))(x, k)
+        gr = jax.grad(loss(lambda xx, kk: reference_conv2d(
+            lay, xx, kk, groups=m.group)), argnums=(0, 1))(x, k)
+    for a, r in zip(gm, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r), rtol=1e-4,
+                                   atol=1e-4 * float(jnp.max(jnp.abs(r))))
+
+
+def test_place_phase_has_no_scatter():
+    """A layer with 18 channel super-steps and a tile of 3 oc super-steps:
+    every array pass still runs (one dot_general each), the only scatter
+    left is the in-forward weight build, and `place` is one gather per
+    tile."""
+    m = _bench_layer("DN40-b2l3")
+    lay = m.layer
+    x = jax.ShapeDtypeStruct((2, lay.ic, lay.i_h, lay.i_w), jnp.float32)
+    k = jax.ShapeDtypeStruct((lay.k_h, lay.k_w, lay.ic // m.group, lay.oc),
+                             jnp.float32)
+    low = mapped_conv2d_jit.lower(m, x, k)
+    passes = sum(R * C * n for R, C, n in _tile_blocks(m))
+    assert low.as_text().count("stablehlo.dot_general") == passes == 21
+    ops = re.findall(r" (scatter|gather)\(.*?op_name=\"([^\"]*)\"",
+                     low.compile().as_text())
+    scatters = [name for op, name in ops if op == "scatter"]
+    assert scatters and all("/weights/" in n for n in scatters), scatters
+    placed = [name for op, name in ops if "/place/" in name]
+    assert len(placed) == layer_schedule(m).placements == len(m.tiles)
+    assert all(op == "gather" for op, name in ops if "/place/" in name)
+
+
+@pytest.mark.parametrize("name,tiles,former", [("cnn8", 6, 14),
+                                               ("densenet40", 58, 818)])
+def test_placement_totals_bench_mappings(name, tiles, former):
+    """One placement per tile on the benchmark's mappings, where the
+    former executor scattered once per channel super-step, oc super-step
+    and window shape; describe() reports the total."""
+    net = _bench_mapping(name)
+    assert sum(R * C * n for m in net.layers
+               for R, C, n in _tile_blocks(m)) == former
+    plan = compile_plan(net, executor_policy="mapped")
+    assert plan.placements == tiles == sum(len(m.tiles) for m in net.layers)
+    assert f" placements={tiles} " in plan.describe()
+    per_layer = re.findall(r"([^\s(,]+):mapped(?:\[[^]]*\])?\(p=(\d+)\)",
+                           plan.describe())
+    assert per_layer == [(m.layer.name, str(len(m.tiles)))
+                         for m in net.layers]
 
 
 @pytest.mark.slow
