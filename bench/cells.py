@@ -5,7 +5,14 @@ mix; everything that belongs to one of them sits in a file of its own:
 
 * ``configs[i].file`` — the configuration: its layers as they are run,
   where the mapping comes from, the precision the served programs
-  compute in, and the limit of the output check;
+  compute in, and the limit of the output check.  Its layers may each
+  carry ``"glue"``, the fields of the program's ``GlueSpec`` for that
+  layer (every layer or none; without it the program infers a chain or
+  a DenseNet concat from the channel counts);
+* the configuration's ``"reference"`` — its plain reference, a module
+  under ``bench/`` named relative to the repository root, with
+  ``make_data``, ``forward_blocks`` and ``useful_flops_per_image``;
+  without the key, ``bench/reference.py``;
 * ``bench/traffic/<traffic>.json`` — the traffic mix's parameters;
 * ``bench/cells/<workload>.json`` — what is fixed for this cell alone
   (its offered rate);
@@ -89,8 +96,26 @@ def metric_reader(name: str, root: Path = ROOT) -> Callable:
     path = root / "bench" / "metrics" / f"{name}.py"
     if not path.exists():
         path = path.with_name(name.split(".")[0] + ".py")
+    return _load(path, "bench_metric_" + name).read
+
+
+def reference_module(config: dict, root: Path = ROOT):
+    """The configuration's plain reference: the module its
+    ``"reference"`` names, relative to ``root`` and under its ``bench/``,
+    else ``bench/reference.py``."""
+    rel = config.get("reference", "bench/reference.py")
+    path = (root / rel).resolve()
+    if not path.is_relative_to((root / "bench").resolve()):
+        raise ValueError(f"{config.get('name')}: reference {rel!r} is not "
+                         f"a module under bench/")
+    return _load(path, "bench_reference_" + path.stem)
+
+
+def _load(path: Path, name: str):
+    """The module in the file ``path``, run as ``name`` (dots and dashes
+    made underscores)."""
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+        name.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod

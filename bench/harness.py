@@ -2,18 +2,23 @@
 ``sweep.py`` and ``control.py``.
 
 Everything the benchmark takes from the program is here and in
-``window.py``: the mapping search (``core.map_net``), the plan ladder
+``window.py``: the mapping search (``core.map_net``) with the
+configuration's layer graph (``core.GlueSpec``), the plan ladder
 (``launch.batching.PlanLadder`` over ``batch_tiers``), the coalescer and
 ``exec.execute_plan``.  The weights, the images, the traffic and the
-reference are the benchmark's own.
+reference (the configuration's own module, ``cells.reference_module``)
+are the benchmark's own.
 """
 from __future__ import annotations
 
+import dataclasses
 import json
 import os
 import sys
 import time
 from pathlib import Path
+
+import cells
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -87,10 +92,29 @@ class CompileCounter:
             self.count += 1
 
 
+def layer_glue(config: dict):
+    """The configuration's layer graph as one ``GlueSpec`` a layer, its
+    JSON lists as tuples; None where no layer carries ``"glue"``."""
+    from repro.core import GlueSpec
+    layers = config["layers"]
+    lacking = [l["name"] for l in layers if "glue" not in l]
+    if len(lacking) == len(layers):
+        return None
+    if lacking:
+        raise ValueError(f"{config['name']}: {lacking} carry no \"glue\"; "
+                         f"give every layer its glue, or none")
+    return tuple(GlueSpec(**{k: tuple(v) if isinstance(v, list) else v
+                             for k, v in l["glue"].items()})
+                 for l in layers)
+
+
 def build_mapping(config: dict):
-    """The TetrisG-SDK mapping of the configuration's chain, checked
-    against the grouping and pruning the configuration states."""
+    """The TetrisG-SDK mapping of the configuration's layers, checked
+    against the grouping and pruning the configuration states, with the
+    layer graph its ``"glue"`` gives (else ``glue=None``: the program
+    infers chain and concat)."""
     from repro.core import ArrayConfig, ConvLayerSpec, MacroGrid, map_net
+    glue = layer_glue(config)
     m = config["mapping"]
     specs = [ConvLayerSpec(name=l["name"], i_w=l["size"], i_h=l["size"],
                            k_w=l["kernel"], k_h=l["kernel"], ic=l["ic"],
@@ -111,17 +135,17 @@ def build_mapping(config: dict):
                 f"{l['name']}: the mapping groups by {lm.group} and prunes "
                 f"{pruned}; the configuration states groups={l['groups']} "
                 f"pruned={l['pruned']}")
-    return net
+    return net if glue is None else dataclasses.replace(net, glue=glue)
 
 
 class Server:
     """One configuration set up to serve: weights and image pool from the
     seed, the mapping, and the plan ladder (the mix's ``tiers``, else
     ``batch_tiers(max_batch)``) with every tier compiled and run twice.
-    ``setup`` holds the seconds of each phase."""
+    ``setup`` holds the seconds of each phase.  ``reference`` is the
+    configuration's own reference module."""
 
     def __init__(self, config: dict, mix: dict, seed: int):
-        import reference
         from repro.core import memo
         from repro.exec import donation_supported
         from repro.launch.batching import batch_tiers
@@ -129,6 +153,7 @@ class Server:
         enable_compile_cache()
         memo.set_disk_cache(CACHE / "mapping")
         self.config, self.mix = config, mix
+        self.reference = cells.reference_module(config)
         self.counter = CompileCounter()
         self.setup = {}
         t0 = time.perf_counter()
@@ -144,12 +169,12 @@ class Server:
         self.ladder = self._warm(mix.get("tiers")
                                  or batch_tiers(self.max_batch))
         self.setup["ladder_warm_s"] = time.perf_counter() - t2
-        self.flops_per_image = reference.useful_flops_per_image(config)
+        self.flops_per_image = self.reference.useful_flops_per_image(
+            config)
 
     def reseed(self, seed: int) -> None:
         import numpy as np
-        import reference
-        self.kernels, pool = reference.make_data(
+        self.kernels, pool = self.reference.make_data(
             self.config, int(self.mix["pool_images"]), seed)
         self.pool = np.asarray(pool)
 
@@ -198,12 +223,11 @@ class Server:
         """The reference over the kept requests' images, concatenated in
         the order of ``ids``."""
         import numpy as np
-        import reference
         rows = np.concatenate([self.pool[sched.starts[j]:sched.starts[j]
                                          + sched.sizes[j]] for j in ids])
-        return reference.forward_blocks(self.config, self.kernels, rows,
-                                        block=self.max_batch,
-                                        operands=operands)
+        return self.reference.forward_blocks(
+            self.config, self.kernels, rows, block=self.max_batch,
+            operands=operands)
 
 
 def per_request_errors(sched, ids, outputs, ref):

@@ -9,8 +9,8 @@ seed, the TetrisG-SDK mapping (through a disk cache under
 ``bench/.cache``), the plan ladder compiled and every tier run twice.
 Then the window serves the cell's traffic for ``--seconds``
 (``bench/window.py``).  After it the device's peak memory is read and
-the kept outputs are compared with the plain float32 reference
-(``bench/reference.py``).
+the kept outputs are compared with the plain float32 reference (the
+module the configuration names, else ``bench/reference.py``).
 
 ``--trace 0`` reports the cell's end-to-end metrics; ``--trace 1``
 records a profiler trace of the window's last ``TRACE_SECONDS`` and
