@@ -281,6 +281,12 @@ def train_plan(net: NetworkMapping, *, steps: int = 10, batch: int = 8,
     from repro.launch.mesh import pad_to_data_axis
     if accum < 1 or batch % accum:
         raise ValueError(f"accum={accum} must divide batch={batch}")
+    if net.glue is not None and any(g.kind in ("branch", "join")
+                                    for g in net.glue):
+        raise ValueError(
+            f"{net.name}: train_plan trains chained CNNs only; this "
+            f"mapping has branch glue (Inception modules), which it does "
+            f"not train through")
     micro = batch // accum
     micro = pad_to_data_axis(micro, mesh) if mesh is not None else micro
     batch = micro * accum

@@ -106,7 +106,7 @@ SCHEMA_VERSION = 2      # 2: op-kind axis on layer specs (ISSUE 8)
 #: Separate version for compiled NetworkPlan entries (:func:`cached_plan`)
 #: — bump when the plan IR (exec/plan.py dataclasses) or the compile
 #: semantics change without the mapping schema moving.
-PLAN_VERSION = 5        # 5: LayerPlan.note (sdk tiling refusals)
+PLAN_VERSION = 6        # 6: GlueSpec pool, branch/join kinds
 
 #: Version for persisted autotuner winners (:func:`load_tuning` /
 #: :func:`store_tuning`) — bump when the TunedConfig schema or the

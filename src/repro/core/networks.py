@@ -16,18 +16,22 @@ Invariants:
 
 * layer order is forward-pass order; consecutive specs are chainable
   (next ic == this oc, or == carried channels + oc for dense blocks) —
-  relied on by ``mapped_net_apply`` and its tests;
+  relied on by ``mapped_net_apply`` and its tests — except in the
+  networks ``GLUE`` names, whose branches re-read a module's input;
 * ``stride``/``groups`` stay in the spec (MobileNet depthwise carries
   ``groups=ic``); nothing is pre-lowered, so every algorithm sees the
   layer the paper's tables describe;
-* ``NETWORKS`` maps the paper's four benchmark names to zero-argument
-  constructors (the benchmark scripts' registry).
+* ``NETWORKS`` maps the benchmark names to zero-argument constructors
+  (the benchmark scripts' registry); ``GLUE`` maps the names of the
+  networks that are not chains to their layer graph, one ``GlueSpec`` a
+  layer (:func:`with_glue` attaches it to a mapping).
 """
 from __future__ import annotations
 
-from typing import List
+import dataclasses
+from typing import List, Tuple
 
-from .types import ConvLayerSpec
+from .types import ConvLayerSpec, GlueSpec
 
 
 def _c(name, i, k, ic, oc, stride=1, groups=1) -> ConvLayerSpec:
@@ -60,6 +64,70 @@ def inception() -> List[ConvLayerSpec]:
         _c("Incep-4e", 14, 5, 32, 128),
         _c("Incep-5a", 7, 5, 32, 128),
     ]
+
+
+#: GoogLeNet's nine Inception modules (Szegedy et al., "Going deeper with
+#: convolutions", arXiv:1409.4842, Table 1): name, input channels,
+#: feature-map size, then #1x1, #3x3 reduce, #3x3, #5x5 reduce, #5x5 and
+#: pool proj output channels.
+GOOGLENET_MODULES = (
+    ("3a", 192, 28, 64, 96, 128, 16, 32, 32),
+    ("3b", 256, 28, 128, 128, 192, 32, 96, 64),
+    ("4a", 480, 14, 192, 96, 208, 16, 48, 64),
+    ("4b", 512, 14, 160, 112, 224, 24, 64, 64),
+    ("4c", 512, 14, 128, 128, 256, 24, 64, 64),
+    ("4d", 512, 14, 112, 144, 288, 32, 64, 64),
+    ("4e", 528, 14, 256, 160, 320, 32, 128, 128),
+    ("5a", 832, 7, 256, 160, 320, 32, 128, 128),
+    ("5b", 832, 7, 384, 192, 384, 48, 128, 128),
+)
+
+#: Modules a 3x3/2 max-pool (ceil mode: 28 -> 14 -> 7) precedes.
+GOOGLENET_STAGE_POOLS = ("4a", "5a")
+
+
+def inception_modules(modules, stage_pools=()
+                      ) -> Tuple[List[ConvLayerSpec], Tuple[GlueSpec, ...]]:
+    """``(layers, glue)`` of a stack of Inception modules, each given as
+    in :data:`GOOGLENET_MODULES`; a 3x3/2 max-pool precedes the modules
+    named in ``stage_pools``.  Six convs a module, in the order 1x1, 3x3
+    reduce, 3x3, 5x5 reduce, 5x5, pool proj (sizes padded: +2 for 3x3,
+    +4 for 5x5).  In the glue the 1x1 saves the module input and opens
+    the branches; each branch hands the module input on, so the two
+    reduces and the pool proj read it (the pool proj behind a 3x3/1
+    max-pool), and the join concatenates 1x1, 3x3, 5x5 and pool proj;
+    ReLU after every conv."""
+    layers: List[ConvLayerSpec] = []
+    glue: List[GlueSpec] = []
+    for name, ic, size, c1, r3, c3, r5, c5, pp in modules:
+        p = f"G{name}"
+        layers += [_c(f"{p}-1x1", size, 1, ic, c1),
+                   _c(f"{p}-3x3r", size, 1, ic, r3),
+                   _c(f"{p}-3x3", size + 2, 3, r3, c3),
+                   _c(f"{p}-5x5r", size, 1, ic, r5),
+                   _c(f"{p}-5x5", size + 4, 5, r5, c5),
+                   _c(f"{p}-pool", size, 1, ic, pp)]
+        stage = "max3s2" if name in stage_pools else "none"
+        glue += [GlueSpec(kind="branch", act="relu", save=True, pool=stage),
+                 GlueSpec(act="relu"),
+                 GlueSpec(kind="branch", act="relu"),
+                 GlueSpec(act="relu"),
+                 GlueSpec(kind="branch", act="relu"),
+                 GlueSpec(kind="join", act="relu", pool="max3s1")]
+    return layers, tuple(glue)
+
+
+def googlenet() -> List[ConvLayerSpec]:
+    """GoogLeNet's nine Inception modules whole (:func:`inception_modules`
+    of :data:`GOOGLENET_MODULES`); the stem, the average pool and the
+    classifiers are left out.  Not a chain: serve it with its glue,
+    :func:`googlenet_glue`."""
+    return inception_modules(GOOGLENET_MODULES, GOOGLENET_STAGE_POOLS)[0]
+
+
+def googlenet_glue() -> Tuple[GlueSpec, ...]:
+    """The layer graph of :func:`googlenet`, one ``GlueSpec`` a layer."""
+    return inception_modules(GOOGLENET_MODULES, GOOGLENET_STAGE_POOLS)[1]
 
 
 def densenet40(growth: int = 12, init_ch: int = 16) -> List[ConvLayerSpec]:
@@ -109,4 +177,17 @@ NETWORKS = {
     "inception": inception,
     "densenet40": densenet40,
     "mobilenet": mobilenet,
+    "googlenet": googlenet,
 }
+
+GLUE = {"googlenet": googlenet_glue}
+
+
+def with_glue(name: str, mapping):
+    """``mapping`` of the network ``name`` with its layer graph (its
+    first ``len(mapping.layers)`` glue entries); unchanged for the
+    networks whose chain or concat glue ``compile_plan`` infers."""
+    if name not in GLUE:
+        return mapping
+    return dataclasses.replace(mapping,
+                               glue=GLUE[name]()[:len(mapping.layers)])

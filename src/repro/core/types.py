@@ -104,6 +104,7 @@ def matmul_spec(name: str, m: int, d: int, f: int,
 _GLUE_PRE = ("none", "layernorm")
 _GLUE_ACT = ("none", "relu", "gelu", "silu")
 _GLUE_POST = ("none", "attention")
+_GLUE_POOL = ("none", "max3s1", "max3s2")
 
 
 @dataclass(frozen=True)
@@ -111,27 +112,36 @@ class GlueSpec:
     """Inter-layer glue for one plan step — everything between two mapped
     layers that the CIM macros do not execute.
 
-    Applied around layer i's mapped op in this order: ``save`` captures
-    the (pre-norm) input for a later residual; ``pre`` normalizes the
+    Applied around layer i's mapped op in this order: ``pool`` runs a
+    3x3 max-pool on the layer's input (``"max3s1"``: stride 1, -inf padding, size
+    kept; ``"max3s2"``: stride 2 in ceil mode); the spatial adapter then
+    fits it to the layer; ``save`` captures that input for a later
+    residual or for the module's branches; ``pre`` normalizes the
     mapped op's input; ``act`` activates its output (overriding any
     global activation for this layer); ``post='attention'`` runs the
     opaque flash-attention stage on a fused qkv output (``heads =
     (n_q, n_kv, head_dim)``); ``kind`` then forms the next layer's
     input — "chain" passes through, "concat" is the DenseNet skip,
-    "residual" pops the innermost saved input and adds it.
+    "residual" pops the innermost saved input and adds it, "branch"
+    appends the output to the module's open branches and hands the next
+    layer the innermost saved input (the module's input, which every
+    branch reads), "join" appends the output, concatenates the branches
+    on channels in layer order and pops the module's saved input.
     """
 
     kind: str = "chain"        # "chain" | "concat" | "residual" | "last"
+    #                          # | "branch" | "join"
     pre: str = "none"
     act: str = "none"
     post: str = "none"
     save: bool = False
     heads: "Tuple[int, int, int]" = None  # (n_q_heads, n_kv_heads, head_dim)
     causal: bool = True
+    pool: str = "none"         # "none" | "max3s1" | "max3s2"
 
     def __post_init__(self):
         if self.kind not in ("chain", "concat", "residual", "last",
-                             "layerwise"):
+                             "layerwise", "branch", "join"):
             raise ValueError(f"unknown glue kind {self.kind!r}")
         if self.pre not in _GLUE_PRE:
             raise ValueError(f"unknown glue pre {self.pre!r}")
@@ -139,6 +149,8 @@ class GlueSpec:
             raise ValueError(f"unknown glue act {self.act!r}")
         if self.post not in _GLUE_POST:
             raise ValueError(f"unknown glue post {self.post!r}")
+        if self.pool not in _GLUE_POOL:
+            raise ValueError(f"unknown glue pool {self.pool!r}")
         if (self.heads is not None) != (self.post == "attention"):
             raise ValueError("heads required iff post='attention'")
 

@@ -21,11 +21,16 @@ structured `repro.core.GlueSpec` — ``kind`` is the carry rule below,
 plus optional per-layer stages the CIM macros do not execute: ``pre``
 layernorm passthrough (:func:`layernorm`), ``act`` activations
 (:data:`ACTIVATIONS`), ``save``/``kind="residual"`` for transformer
-residual adds, and the ``post="attention"`` opaque stage
+residual adds, the ``post="attention"`` opaque stage
 (:func:`attention_stage`) that turns a fused qkv projection's output
 into attention context via `kernels.flash_attention` between two mapped
-matmuls.  This module stays a leaf — jax + kernels only — so every
-executor layer can import it.
+matmuls, and the multi-branch module glue of GoogLeNet's Inception
+modules: ``pool`` runs a 3x3 max-pool before the spatial adapter
+(:func:`stage_pool`), and ``kind="branch"``/``"join"`` collect the
+branch outputs (each branch hands the next layer the module input
+again) and concatenate them.  :func:`glue_floats` counts what the glue
+writes, from the static shapes alone.  This module stays a leaf — jax +
+kernels only — so every executor layer can import it.
 """
 from __future__ import annotations
 
@@ -37,9 +42,12 @@ from repro.core.types import GlueSpec  # noqa: F401  (re-export)
 #: Post-layer carry updates a plan can prescribe (LayerPlan.glue.kind):
 #: "chain" — carry becomes the layer's output; "concat" — carry becomes
 #: concat(center-cropped layer input, output); "residual" — carry becomes
-#: saved input + output (transformer skip); "last" — final layer, the
-#: output IS the result.
-GLUE_KINDS = ("chain", "concat", "residual", "last")
+#: saved input + output (transformer skip); "branch" — the output joins
+#: the module's open branches and the carry becomes the innermost saved
+#: input (the module's input) again; "join" — the last branch: carry becomes
+#: the branches concatenated in layer order, and the module's saved
+#: input is popped; "last" — final layer, the output IS the result.
+GLUE_KINDS = ("chain", "concat", "residual", "branch", "join", "last")
 
 #: Per-layer glue activations (GlueSpec.act).  A layer whose glue names
 #: one overrides any network-global ``activation`` for that layer.
@@ -112,6 +120,82 @@ def fit_spatial(x: jnp.ndarray, i_h: int, i_w: int) -> jnp.ndarray:
             lo = (-d) // 2
             x = jax.lax.slice_in_dim(x, lo, lo + tgt, axis=x.ndim + ax)
     return x
+
+
+def pool_size(n: int, pool: str) -> int:
+    """Spatial size after a ``GlueSpec.pool`` stage: 3x3/1 with one
+    pixel of -inf padding keeps it; 3x3/2 in ceil mode gives
+    ``ceil((n - 3) / 2) + 1`` (28 -> 14 -> 7, GoogLeNet's stage pools)."""
+    if pool == "none" or pool == "max3s1":
+        return n
+    return -(-(n - 3) // 2) + 1
+
+
+def stage_pool(x: jnp.ndarray, pool: str) -> jnp.ndarray:
+    """The ``GlueSpec.pool`` stage on ``x (B, C, H, W)``: a 3x3 max-pool,
+    stride 1 with one -inf pixel each side (the Inception pool branch),
+    or stride 2 in ceil mode, -inf padding only past the far edge."""
+    if pool == "none":
+        return x
+    if pool == "max3s1":
+        pads = ((1, 1), (1, 1))
+        stride = 1
+    else:
+        pads = tuple((0, 2 * (pool_size(n, pool) - 1) + 3 - n)
+                     for n in x.shape[-2:])
+        stride = 2
+    return jax.lax.reduce_window(
+        x, -jnp.inf, jax.lax.max, (1, 1, 3, 3), (1, 1, stride, stride),
+        ((0, 0), (0, 0)) + pads)
+
+
+def glue_floats(layers, specs) -> int:
+    """The floats per image that the glue between ``layers``
+    (``ConvLayerSpec``, in plan order) writes, joined by ``specs`` (one
+    resolved ``GlueSpec`` each), from the static shapes with the network
+    input at the first layer's padded size: each pool (a ``pool`` stage
+    and the adapter's 2x2 pools), each pad or crop of one axis, the
+    DenseNet skip's crop and concat, and a join's concat.  Activations,
+    norms, residual adds and the attention stage are not counted."""
+    first = layers[0]
+    c, h, w = first.ic, first.i_h, first.i_w
+    saved, branches = [], []
+    written = 0
+    for lay, g in zip(layers, specs):
+        if g.pool != "none":
+            h, w = pool_size(h, g.pool), pool_size(w, g.pool)
+            written += c * h * w
+        while h >= 2 * lay.i_h and w >= 2 * lay.i_w:    # fit_spatial
+            h, w = h // 2, w // 2
+            written += c * h * w
+        if h != lay.i_h:
+            h = lay.i_h
+            written += c * h * w
+        if w != lay.i_w:
+            w = lay.i_w
+            written += c * h * w
+        if g.save:
+            saved.append((c, h, w))
+        oh, ow = lay.o_h, lay.o_w
+        oc = g.heads[0] * g.heads[2] if g.post == "attention" else lay.oc
+        if g.kind == "concat":
+            if (h, w) != (oh, ow):
+                written += c * oh * ow
+            written += (c + oc) * oh * ow
+            c, h, w = c + oc, oh, ow
+        elif g.kind == "join":
+            c, h, w = sum(branches) + oc, oh, ow
+            written += c * h * w
+            saved.pop()
+            branches = []
+        elif g.kind == "branch":
+            branches.append(oc)
+            c, h, w = saved[-1]
+        else:
+            if g.kind == "residual":
+                saved.pop()
+            c, h, w = oc, oh, ow
+    return written
 
 
 def center_crop(x: jnp.ndarray, h: int, w: int) -> jnp.ndarray:

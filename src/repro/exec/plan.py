@@ -24,8 +24,9 @@ in without touching dispatch::
   (`macro_mesh_fits`), so dispatch never re-fits;
 * **check_glue** — inter-layer glue: plain chain / DenseNet concat
   classified from channel arithmetic (exec/glue.py) for CNNs, or the
-  mapping's explicit `GlueSpec` tuple validated by carry simulation —
-  a mis-chained network fails at compile, not mid-forward;
+  mapping's explicit `GlueSpec` tuple validated by carry simulation
+  (residual saves, Inception branches and joins) — a mis-chained
+  network fails at compile, not mid-forward;
 * **estimate_memory** — per-layer live-activation + shifted-weight
   byte estimates from the LayerMapping itself (exec/memory.py);
 * **segment** — rematerialization boundaries under the requested
@@ -55,7 +56,7 @@ from repro.kernels.im2win_conv import (default_vmem_budget, sdk_conv_cycles,
 from repro.launch.sharding import macro_mesh_fits
 from . import memory as memlib
 from . import remat as rematlib
-from .glue import resolve_chain
+from .glue import glue_floats, resolve_chain
 
 #: Executors a plan can dispatch a layer to.  "matmul" is the MXU path
 #: for ``op="matmul"`` layers (kernels/matmul_exec.py:
@@ -164,6 +165,17 @@ class NetworkPlan:
         return sum(lp.schedule.placements for lp in self.layers
                    if lp.executor == "mapped")
 
+    @property
+    def glue_bytes_per_image(self) -> int:
+        """Float32 bytes per image the glue writes between the mapped
+        layers of a chained plan — pads and crops, pools, concats —
+        counted from the static shapes (`glue.glue_floats`)."""
+        if not self.chained:
+            return 0
+        return memlib.ITEMSIZE * glue_floats(
+            [lp.mapping.layer for lp in self.layers],
+            [lp.glue for lp in self.layers])
+
     def describe(self) -> str:
         execs = ",".join(
             f"{lp.mapping.layer.name}:{lp.executor}"
@@ -176,6 +188,7 @@ class NetworkPlan:
         seg = f" segments={len(self.segments)}" if self.segments else ""
         return (f"plan[{self.net.name}] layers={len(self.layers)} "
                 f"steps={self.total_steps} placements={self.placements} "
+                f"glue_bytes={self.glue_bytes_per_image} "
                 f"mesh={tag} "
                 f"lookahead={self.lookahead} "
                 f"peak_mem={self.peak_bytes / 1e6:.1f}MB{seg} ({execs})")
@@ -363,13 +376,14 @@ def pass_check_glue(d: PlanDraft) -> PlanDraft:
     glue, carries = [], []
     carry_c = net.layers[0].layer.ic
     saved: list = []                # channel widths of GlueSpec.save stack
+    branches: list = []             # (c, h, w) of the open module branches
     for i, m in enumerate(net.layers):
         lay = m.layer
         carries.append(carry_c)
         if net.glue is not None:
             spec = net.glue[i]
-            carry_c, saved = _check_explicit_glue(net, i, spec, carry_c,
-                                                  saved)
+            carry_c, saved, branches = _check_explicit_glue(
+                net, i, spec, carry_c, saved, branches)
         else:
             if i + 1 < n:
                 nxt = net.layers[i + 1].layer
@@ -379,6 +393,10 @@ def pass_check_glue(d: PlanDraft) -> PlanDraft:
                 spec = GlueSpec(kind="last")
             carry_c = net.layers[i + 1].layer.ic if i + 1 < n else lay.oc
         glue.append(spec)
+    if branches:
+        raise ValueError(
+            f"{net.name}: a module is left open at the end: "
+            f"{len(branches)} branch output(s) never joined")
     if net.glue is not None and saved:
         raise ValueError(
             f"{net.name}: {len(saved)} saved residual input(s) never "
@@ -460,11 +478,13 @@ def _compile(net: NetworkMapping, execs: Tuple[str, ...], mesh,
 
 
 def _check_explicit_glue(net: NetworkMapping, i: int, spec: GlueSpec,
-                         carry_c: int, saved: list):
+                         carry_c: int, saved: list, branches: list):
     """Compile-time channel simulation of one explicit-glue step: what
     `resolve_chain` does for inferred CNN glue, generalized to the
-    save/residual stack and the attention stage.  Returns the carry
-    channel count entering layer i+1 and the updated saved stack —
+    save/residual stack, the attention stage and Inception modules (a
+    branch hands the next layer the saved module input; a join
+    concatenates the branches).  Returns the carry channel count
+    entering layer i+1 and the updated saved stack and open branches —
     raising the mis-chaining error here, never mid-forward."""
     lay = net.layers[i].layer
     last = i + 1 == len(net.layers)
@@ -487,7 +507,9 @@ def _check_explicit_glue(net: NetworkMapping, i: int, spec: GlueSpec,
                 f"with oc={(hq + 2 * hkv) * hd}, got op="
                 f"{getattr(lay, 'op', 'conv')!r} oc={lay.oc}")
         out_c = hq * hd
+    out = (out_c, lay.o_h, lay.o_w)
     saved = list(saved)
+    branches = list(branches)
     if spec.save:
         saved.append(carry_c)
     if spec.kind == "residual":
@@ -502,6 +524,29 @@ def _check_explicit_glue(net: NetworkMapping, i: int, spec: GlueSpec,
         nxt_c = out_c
     elif spec.kind == "concat":
         nxt_c = carry_c + out_c
+    elif spec.kind == "branch":
+        if not saved:
+            raise ValueError(f"{lay.name}: kind='branch' with no saved "
+                             f"module input (no earlier glue set "
+                             f"save=True)")
+        branches.append(out)
+        nxt_c = saved[-1]
+    elif spec.kind == "join":
+        if not branches:
+            raise ValueError(f"{lay.name}: kind='join' with no open "
+                             f"branches (no earlier glue set "
+                             f"kind='branch')")
+        if not saved:
+            raise ValueError(f"{lay.name}: kind='join' with no saved "
+                             f"module input to pop")
+        branches.append(out)
+        if len({b[1:] for b in branches}) > 1:
+            raise ValueError(
+                f"{lay.name}: join concatenates branch outputs of "
+                f"different sizes {[b[1:] for b in branches]}")
+        nxt_c = sum(b[0] for b in branches)
+        branches = []
+        saved.pop()
     else:                               # "chain" or final "last"
         nxt_c = out_c
     if not last and net.layers[i + 1].layer.ic != nxt_c:
@@ -509,7 +554,7 @@ def _check_explicit_glue(net: NetworkMapping, i: int, spec: GlueSpec,
         raise ValueError(
             f"cannot chain {lay.name} ({spec.kind}, {nxt_c} carry "
             f"channels) into {nxt.name} (ic={nxt.ic})")
-    return nxt_c, saved
+    return nxt_c, saved, branches
 
 
 def compile_plan(net: NetworkMapping, *,

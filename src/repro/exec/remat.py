@@ -15,7 +15,10 @@ output is saved for downstream concats, so the save stack only drains
 at the 1x1 transitions — the block is atomic.  Cutting mid-group would
 force a saved tensor to cross a checkpoint boundary, which
 `jax.checkpoint` cannot express over our single-carry segment
-interface.
+interface.  The same holds inside an Inception module (branch glue):
+its saved input and its open branch outputs are live until the join,
+so a cut falls only after a join (or a chain layer outside modules),
+where the saved stack is empty and no branch is open.
 
 **Selection.**  Greedy, in the style of chainer-compiler's
 ``recompute.cc`` (pick recompute sets from the graph's own per-node
@@ -80,20 +83,23 @@ def canonical_remat(remat: RematSpec):
 
 def allowed_cuts(glue) -> Tuple[int, ...]:
     """Indices i where cutting after layer i is legal (boundary rule
-    above): chain glue with an empty outstanding residual-save stack —
-    mirroring `_check_explicit_glue`'s carry simulation, ``save=True``
-    pushes and ``kind='residual'`` pops.  Concat glue never cuts (the
-    never-split rule: the carry there is the concatenated block stack,
-    the worst possible boundary).  The last layer is never a cut (a
-    trailing empty segment is meaningless)."""
-    saved = 0
+    above): chain or join glue with an empty outstanding save stack and
+    no open branch — mirroring `_check_explicit_glue`'s carry
+    simulation, ``save=True`` pushes, ``kind='residual'`` and
+    ``kind='join'`` pop, ``kind='branch'`` opens a branch and the join
+    closes them.  Concat glue never cuts (the never-split rule: the
+    carry there is the concatenated block stack, the worst possible
+    boundary).  The last layer is never a cut (a trailing empty segment
+    is meaningless)."""
+    saved = branches = 0
     out = []
     for i, g in enumerate(glue[:-1] if glue else []):
         if g.save:
             saved += 1
-        if g.kind == "residual":
+        if g.kind in ("residual", "join"):
             saved -= 1
-        if g.kind == "chain" and saved == 0:
+        branches = 0 if g.kind == "join" else branches + (g.kind == "branch")
+        if g.kind in ("chain", "join") and saved == 0 and branches == 0:
             out.append(i)
     return tuple(out)
 

@@ -40,7 +40,7 @@ from repro.cnn.mapped_net import mapped_conv2d_jit, mapped_conv2d_traced
 from repro.launch.device import compile_stats
 from repro.launch.tracing import GLUE, span
 from .glue import (ACTIVATIONS, attention_stage, center_crop, fit_spatial,
-                   layernorm)
+                   layernorm, stage_pool)
 from .plan import LayerPlan, NetworkPlan, mesh_axes
 
 
@@ -106,8 +106,9 @@ def _forward(plan: NetworkPlan, kernels, x: jnp.ndarray, mesh,
     program input to the start is free.
 
     Each layer runs in its named scope ``L{i:02d}.{name}``; what lies
-    outside its executor runs under ``glue``, the executor's own work
-    under its phases (launch/tracing.PHASES)."""
+    outside its executor runs under ``glue`` (a ``pool`` stage and a
+    module's ``join`` in sub-scopes of those names), the executor's own
+    work under its phases (launch/tracing.PHASES)."""
     lay0 = plan.layers[0].mapping.layer
     if x.shape[1] != lay0.ic:
         raise ValueError(f"{lay0.name}: input has {x.shape[1]} channels,"
@@ -124,16 +125,22 @@ def _forward(plan: NetworkPlan, kernels, x: jnp.ndarray, mesh,
     def _segment(s, e, x, seg_kernels, seg_consts):
         """Layers [s, e) on carry ``x`` — the whole net in one call for
         unsegmented plans, one `jax.checkpoint` body per plan segment
-        otherwise.  The saved-residual stack is segment-local: the
-        segment pass only cuts where it is empty (exec/remat.py)."""
+        otherwise.  The saved stack and a module's open branches are
+        segment-local: the segment pass only cuts where both are empty
+        (exec/remat.py)."""
         seg_kernels = list(seg_kernels)
-        saved = []                  # GlueSpec.save stack (residual bases)
+        saved = []                  # GlueSpec.save stack (residual bases,
+        #                           # module inputs)
+        branches = []               # outputs of the open module's branches
         for i in range(s, e):
             lp = plan.layers[i]
             lay = lp.mapping.layer
             spec = lp.glue
             with jax.named_scope(f"L{i:02d}.{lay.name}"):
                 with jax.named_scope(GLUE):
+                    if spec.pool != "none":
+                        with jax.named_scope("pool"):
+                            x = stage_pool(x, spec.pool)
                     xp = fit_spatial(x, lay.i_h, lay.i_w)
                     if spec.save:   # residual base: the pre-norm input
                         saved.append(xp)
@@ -163,6 +170,17 @@ def _forward(plan: NetworkPlan, kernels, x: jnp.ndarray, mesh,
                         # through the lookahead fence — they are live
                         # carries, not kernel-side prep
                         x = saved.pop() + y
+                    elif spec.kind == "join":
+                        # branch order and widths were checked at
+                        # compile time; the module input is done with
+                        with jax.named_scope("join"):
+                            x = jnp.concatenate(branches + [y], axis=1)
+                        branches = []
+                        saved.pop()
+                    elif spec.kind == "branch":
+                        # the module's next branch reads its input
+                        branches.append(y)
+                        x = saved[-1]
                     else:           # "chain" / "last"
                         x = y
                     # cross-layer pipeline depth (plan.lookahead, a

@@ -338,10 +338,12 @@ def _build_mapping(cfg: WorkerConfig):
     kw = {"groups": tuple(cfg.groups)} if cfg.alg == "TetrisG-SDK" else {}
     array = ArrayConfig(*cfg.array)
     if cfg.p_max is not None:
-        return grid_search(cfg.net, layers, array, cfg.p_max, cfg.alg,
-                           **kw).best
-    grid = MacroGrid(*cfg.grid) if cfg.grid is not None else MacroGrid()
-    return map_net(cfg.net, layers, array, cfg.alg, grid, **kw)
+        mapping = grid_search(cfg.net, layers, array, cfg.p_max, cfg.alg,
+                              **kw).best
+    else:
+        grid = MacroGrid(*cfg.grid) if cfg.grid is not None else MacroGrid()
+        mapping = map_net(cfg.net, layers, array, cfg.alg, grid, **kw)
+    return networks.with_glue(cfg.net, mapping)
 
 
 def worker_env(wid: int, cfg: WorkerConfig,

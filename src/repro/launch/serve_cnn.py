@@ -74,7 +74,8 @@ def _parse_grid(text: str) -> MacroGrid:
 def map_for_serving(net: str, array: ArrayConfig, algorithm: str,
                     grid: MacroGrid = None, p_max: int = None,
                     groups=(1, 2, 4)):
-    """Map ``net`` for serving (fixed grid or Alg 2 budget sweep) and
+    """Map ``net`` for serving (fixed grid or Alg 2 budget sweep), with
+    its layer graph where it is not a chain (``networks.with_glue``), and
     return ``(mapping, search_seconds)``.  With a warm disk cache
     (``memo.set_disk_cache`` / ``REPRO_MAPPING_CACHE``) a cold process
     performs zero search-table builds — asserted in tests/test_serve_cnn.
@@ -88,7 +89,7 @@ def map_for_serving(net: str, array: ArrayConfig, algorithm: str,
     else:
         mapping = map_net(net, layers, array, algorithm,
                           grid or MacroGrid(), **kw)
-    return mapping, time.perf_counter() - t0
+    return networks.with_glue(net, mapping), time.perf_counter() - t0
 
 
 def serving_mesh_for(net_mapping, batch: int):

@@ -111,8 +111,9 @@ def _plan_main(args) -> None:
                          f"(have: {sorted(networks.NETWORKS)})")
     remat = None if args.remat == "off" else (
         args.remat if args.remat == "auto" else int(args.remat))
-    net = map_net(args.plan_net, networks.NETWORKS[args.plan_net](),
-                  ArrayConfig(64, 64), "TetrisG-SDK", MacroGrid(2, 2))
+    net = networks.with_glue(args.plan_net, map_net(
+        args.plan_net, networks.NETWORKS[args.plan_net](),
+        ArrayConfig(64, 64), "TetrisG-SDK", MacroGrid(2, 2)))
     t0 = time.time()
     losses: list = []
     r = train_plan(net, steps=args.steps, batch=args.batch, lr=args.lr,

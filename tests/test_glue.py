@@ -97,3 +97,57 @@ def test_concat_carry_mismatch_raises_at_compile():
                   MacroGrid(1, 1))
     with pytest.raises(ValueError, match="cannot chain b"):
         compile_plan(net, executor_policy="reference")
+
+
+# ---------------------------------------------------------------------------
+# Inception module glue: the pool stages and the static write count
+# ---------------------------------------------------------------------------
+
+def _np_max_pool(x, stride, lo, hi):
+    """3x3 max-pool in numpy over x padded with -inf by ``lo`` before and
+    ``hi`` after each spatial axis."""
+    xp = np.pad(np.asarray(x), ((0, 0), (0, 0), (lo, hi), (lo, hi)),
+                constant_values=-np.inf)
+    n = (xp.shape[-1] - 3) // stride + 1
+    return np.stack([np.stack([xp[..., i * stride:i * stride + 3,
+                                  j * stride:j * stride + 3].max((-2, -1))
+                               for j in range(n)], -1)
+                     for i in range(n)], -2)
+
+
+@pytest.mark.parametrize("size", [4, 7, 8])
+def test_stage_pool_max3s1_keeps_size_with_neg_inf_padding(size):
+    from repro.exec.glue import stage_pool
+    x = _x(size, size) - 10.0          # all negative: a zero pad would win
+    y = stage_pool(x, "max3s1")
+    assert y.shape == x.shape
+    np.testing.assert_array_equal(np.asarray(y), _np_max_pool(x, 1, 1, 1))
+
+
+@pytest.mark.parametrize("size,out", [(28, 14), (14, 7), (8, 4), (7, 3)])
+def test_stage_pool_max3s2_is_ceil_mode(size, out):
+    """GoogLeNet's stage pools: 28 -> 14 -> 7, the last window hanging
+    past the far edge where ceil((n - 3) / 2) rounds up."""
+    from repro.exec.glue import pool_size, stage_pool
+    assert pool_size(size, "max3s2") == out
+    assert pool_size(size, "max3s1") == size == pool_size(size, "none")
+    x = _x(size, size) - 10.0
+    y = stage_pool(x, "max3s2")
+    assert y.shape[-2:] == (out, out)
+    hi = 2 * (out - 1) + 3 - size
+    np.testing.assert_array_equal(np.asarray(y),
+                                  _np_max_pool(x, 2, 0, hi))
+
+
+def test_stage_pool_none_is_identity():
+    from repro.exec.glue import stage_pool
+    x = _x(5, 5)
+    assert stage_pool(x, "none") is x
+
+
+def test_glue_spec_refuses_unknown_pool_and_kind():
+    from repro.core import GlueSpec
+    with pytest.raises(ValueError, match="unknown glue pool"):
+        GlueSpec(pool="avg3s1")
+    with pytest.raises(ValueError, match="unknown glue kind"):
+        GlueSpec(kind="fork")

@@ -265,3 +265,59 @@ def test_env_budget_part_of_cache_key(monkeypatch):
     b = compile_plan(net, executor_policy="mapped", batch=2, remat="auto")
     assert a is not b
     assert len(b.spans) >= len(a.spans)
+
+
+# ------------------------------------------------------- inception modules
+
+def _inception_toy():
+    """Two Inception modules (widths 4-16, a 3x3/2 stage pool between
+    them) with their branch glue."""
+    import dataclasses
+    layers, glue = networks.inception_modules(
+        (("a", 8, 8, 4, 4, 8, 4, 4, 4), ("b", 20, 4, 8, 4, 8, 4, 8, 4)),
+        ("b",))
+    return dataclasses.replace(_net("inception_toy", layers), glue=glue)
+
+
+def test_allowed_cuts_never_fall_inside_a_module():
+    """Cuts only after a join: inside a module the saved input and the
+    open branches are live (and the last layer is never a cut)."""
+    plan = compile_plan(_inception_toy(), executor_policy="mapped")
+    assert allowed_cuts([lp.glue for lp in plan.layers]) == (5,)
+    assert allowed_cuts(networks.googlenet_glue()) == tuple(range(5, 53, 6))
+    with pytest.raises(ValueError, match="illegal remat boundaries"):
+        compile_plan(_inception_toy(), executor_policy="mapped", remat=[2])
+
+
+def test_inception_layer_memory_matches_formulas():
+    """Inside a module the memory pass prices each layer by its own
+    input, as for a chain: a layer after a branch by the module input
+    it reads, the 3x3 and 5x5 by their reduces' outputs."""
+    plan = compile_plan(_inception_toy(), executor_policy="mapped",
+                        batch=2)
+    assert [lp.carry_c for lp in plan.layers] == \
+        [8, 8, 4, 8, 4, 8, 20, 20, 4, 20, 4, 20]
+    for lp in plan.layers:
+        lay = lp.mapping.layer
+        assert lp.act_bytes == 2 * lp.carry_c * lay.i_h * lay.i_w * ITEMSIZE
+        assert lp.mem_bytes == lp.act_bytes + lp.weight_bytes
+    assert plan.unremat_peak_bytes == sum(lp.mem_bytes for lp in plan.layers)
+
+
+def test_remat_at_module_boundary_forward_unchanged():
+    """Segments cut between modules run each module inside one
+    `jax.checkpoint` body: the forward is what the unsegmented plan
+    computes."""
+    net = _inception_toy()
+    flat = compile_plan(net, executor_policy="mapped", batch=2)
+    seg = compile_plan(net, executor_policy="mapped", batch=2, remat=[5])
+    assert seg.spans == ((0, 6), (6, 12))
+    ks, x = _data(net)
+    assert reassoc_error(execute_plan(seg, ks, x),
+                         execute_plan(flat, ks, x)) <= REASSOC_RTOL
+
+
+def test_train_plan_refuses_branch_glue():
+    from repro.cnn.train import train_plan
+    with pytest.raises(ValueError, match="branch glue"):
+        train_plan(_inception_toy(), steps=1, batch=2)

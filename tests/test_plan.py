@@ -367,3 +367,178 @@ def test_plan_vmem_budget_param_and_env(monkeypatch):
     monkeypatch.setenv("REPRO_SDK_VMEM_BUDGET", "-4")
     with pytest.raises(ValueError, match="must be > 0"):
         default_vmem_budget()
+
+
+# ---------------------------------------------------------------------------
+# Inception modules: branch-and-concat glue through the one plan path
+# ---------------------------------------------------------------------------
+
+#: Two Inception modules at widths 4-16 with a 3x3/2 stage pool (ceil
+#: mode, 8 -> 4) before the second: the published module at toy scale.
+TOY_MODULES = (("a", 8, 8, 4, 4, 8, 4, 4, 4),
+               ("b", 20, 4, 8, 4, 8, 4, 8, 4))
+
+
+def _inception_toy(modules=TOY_MODULES, edit=None):
+    """The toy's mapping with its glue; ``edit(layers, glue)`` may change
+    both (lists) before mapping."""
+    import dataclasses
+    layers, glue = networks.inception_modules(modules, ("b",))
+    layers, glue = list(layers), list(glue)
+    if edit is not None:
+        edit(layers, glue)
+    net = _net("inception_toy", layers, groups=(1, 2, 4))
+    return dataclasses.replace(net, glue=tuple(glue))
+
+
+def _bench_googlenet_reference():
+    """bench/references/googlenet.py, loaded as the benchmark loads it
+    (it imports bench/reference.py by its top-level name)."""
+    import importlib.util
+    from pathlib import Path
+    bench = Path(__file__).resolve().parents[1] / "bench"
+    if str(bench) not in sys.path:
+        sys.path.append(str(bench))
+    spec = importlib.util.spec_from_file_location(
+        "bench_reference_googlenet", bench / "references" / "googlenet.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_inception_toy_matches_oracle_and_bench_reference():
+    """The fused plan, the per-layer loop, `execute_oracle` and the
+    benchmark's independent reference agree.  Tolerance as the other
+    plan-vs-oracle tests: rtol 1e-4 with atol scaled to max|ref|, since
+    the mapped executor sums each convolution's terms over array passes
+    in another order than lax.conv (float32 reassociation, not a
+    semantic difference: a lost branch or pool is O(1) off)."""
+    net = _inception_toy()
+    plan = compile_plan(net, executor_policy="mapped", batch=2)
+    assert [lp.glue.kind for lp in plan.layers] == \
+        ["branch", "chain", "branch", "chain", "branch", "join"] * 2
+    assert [lp.carry_c for lp in plan.layers] == \
+        [lp.mapping.layer.ic for lp in plan.layers]
+    ks, x = _data(net)
+    y = execute_plan(plan, ks, x)
+    assert y.shape == (2, 28, 4, 4)
+    r = execute_oracle(plan, ks, x)
+    tol = dict(rtol=1e-4, atol=1e-4 * float(jnp.max(jnp.abs(r))))
+    np.testing.assert_allclose(np.asarray(y), np.asarray(r), **tol)
+    np.testing.assert_allclose(np.asarray(execute_looped(plan, ks, x)),
+                               np.asarray(r), **tol)
+    ref = _bench_googlenet_reference()
+    config = {"layers": [
+        {"name": m.layer.name, "size": m.layer.i_h, "kernel": m.layer.k_h,
+         "ic": m.layer.ic, "oc": m.layer.oc, "stride": m.layer.stride,
+         "groups": m.group} for m in net.layers]}
+    want = ref.forward(config, ks, x)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=1e-4,
+                               atol=1e-4 * float(jnp.max(jnp.abs(want))))
+
+
+def test_inception_glue_bytes_per_image_hand_count():
+    """`NetworkPlan.glue_bytes_per_image` counts every glue write from
+    the static shapes, per image, in float32."""
+    plan = compile_plan(_inception_toy(), executor_policy="mapped")
+    module_a = (4 * 10 * 8 + 4 * 10 * 10    # 3x3: pad H, then W
+                + 4 * 12 * 8 + 4 * 12 * 12  # 5x5: pad H, then W
+                + 8 * 8 * 8                 # pool proj's 3x3/1 pool
+                + 20 * 8 * 8)               # join: 4 + 8 + 4 + 4
+    module_b = (20 * 4 * 4                  # 3x3/2 stage pool, 8 -> 4
+                + 4 * 6 * 4 + 4 * 6 * 6
+                + 4 * 8 * 4 + 4 * 8 * 8
+                + 20 * 4 * 4
+                + 28 * 4 * 4)               # join: 8 + 8 + 8 + 4
+    assert plan.glue_bytes_per_image == 4 * (module_a + module_b)
+    assert f" glue_bytes={4 * (module_a + module_b)} " in plan.describe()
+    # a chain whose sizes line up writes nothing between its layers
+    one = compile_plan(_net(layers=networks.cnn8()[:1]),
+                       executor_policy="mapped")
+    assert one.glue_bytes_per_image == 0
+
+
+def _glue_edit(i, **kw):
+    import dataclasses
+
+    def edit(layers, glue):
+        glue[i] = dataclasses.replace(glue[i], **kw)
+    return edit
+
+
+def _layer_edit(i, **kw):
+    import dataclasses
+
+    def edit(layers, glue):
+        layers[i] = dataclasses.replace(layers[i], **kw)
+    return edit
+
+
+def _drop_last(layers, glue):
+    del layers[-1], glue[-1]
+
+
+@pytest.mark.parametrize("edit,match", [
+    (_glue_edit(0, save=False), "kind='branch' with no saved module input"),
+    (_glue_edit(0, kind="join"), "kind='join' with no open branches"),
+    (_layer_edit(1, ic=4), r"cannot chain Ga-1x1 \(branch, 8 carry "
+                           r"channels\) into Ga-3x3r \(ic=4\)"),
+    (_layer_edit(2, i_w=12, i_h=12), r"join concatenates branch outputs "
+                                     r"of different sizes"),
+    (_drop_last, "module is left open at the end"),
+], ids=["saved_with_empty_stack", "join_without_branches",
+        "branch_channels", "join_sizes", "module_left_open"])
+def test_inception_glue_refused_at_compile(edit, match):
+    net = _inception_toy(edit=edit)
+    with pytest.raises(ValueError, match=match):
+        compile_plan(net, executor_policy="mapped")
+
+
+def test_inception_join_channels_refused_at_compile():
+    """A join whose concatenated widths (4 + 8 + 4 + 4) do not match the
+    next module's input channels."""
+    mods = (TOY_MODULES[0], ("b", 24) + TOY_MODULES[1][2:])
+    with pytest.raises(ValueError, match=r"cannot chain Ga-pool \(join, 20 "
+                                         r"carry channels\) into Gb-1x1 "
+                                         r"\(ic=24\)"):
+        compile_plan(_inception_toy(mods), executor_policy="mapped")
+
+
+def test_googlenet_program_entry_plans_whole():
+    """`networks.googlenet` with its glue, as `serve_cnn` maps it: the
+    nine modules plan whole (no chainable-prefix cut), with a join
+    closing each module and the concat widths of Table 1."""
+    from repro.core import ArrayConfig as AC
+    from repro.launch.fleet import chainable_prefix
+    from repro.launch.serve_cnn import map_for_serving
+    net, _ = map_for_serving("googlenet", AC(512, 512), "TetrisG-SDK")
+    assert net.glue == networks.googlenet_glue()
+    assert chainable_prefix(net) is net
+    plan = compile_plan(net, executor_policy="mapped", batch=1)
+    assert len(plan.layers) == 54
+    joins = [i for i, lp in enumerate(plan.layers) if lp.glue.kind == "join"]
+    assert joins == list(range(5, 54, 6))
+    widths = [sum(r[3:]) - r[4] - r[6] for r in networks.GOOGLENET_MODULES]
+    assert widths == [256, 480, 512, 512, 512, 528, 832, 832, 1024]
+    assert [plan.layers[j + 1].carry_c for j in joins[:-1]] == widths[:-1]
+    stage = [lp.mapping.layer.name for lp in plan.layers
+             if lp.glue.pool == "max3s2"]
+    assert stage == ["G4a-1x1", "G5a-1x1"]
+
+
+def test_with_glue_attaches_only_named_graphs():
+    """`networks.with_glue` leaves a chain's mapping as it is (its glue
+    is inferred) and gives a googlenet prefix the glue of its own
+    layers: the first two modules then plan whole, closed by a join."""
+    from repro.core import ArrayConfig as AC
+    cnn8 = _net()
+    assert networks.with_glue("cnn8", cnn8) is cnn8
+    head = map_net("googlenet", networks.googlenet()[:12], AC(512, 512),
+                   "TetrisG-SDK", MacroGrid(1, 1), groups=(1, 2, 4))
+    net = networks.with_glue("googlenet", head)
+    assert net.glue == networks.googlenet_glue()[:12]
+    plan = compile_plan(net, executor_policy="mapped")
+    assert [lp.glue.kind for lp in plan.layers] == \
+        ["branch", "chain", "branch", "chain", "branch", "join"] * 2
+    assert [lp.carry_c for lp in plan.layers] == \
+        [192, 192, 96, 192, 16, 192, 256, 256, 128, 256, 32, 256]
