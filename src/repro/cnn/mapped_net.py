@@ -319,7 +319,7 @@ def prepared_layer_weights(mapping: LayerMapping, kernel: jnp.ndarray
     the batch, so every tier of a plan ladder — and every co-resident
     plan of the same network — can share ONE prepared copy instead of
     re-deriving the matrices inside each tier's program on every forward
-    (repro.exec.constants.prepare_constants owns the sharing handle)."""
+    (repro.exec.constants.fixed_constants owns the sharing handle)."""
     layer = mapping.layer
     g = mapping.group
     ic_g, oc_g = layer.ic // g, layer.oc // g

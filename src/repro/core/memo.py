@@ -38,8 +38,8 @@ compilation.
 Prepared plan constants (:mod:`repro.exec.constants` — the shifted-weight
 device buffers co-resident plan tiers share) get their own small
 in-memory-only handle cache via :func:`cached_constants`, keyed on the
-net mapping: device buffers never touch the disk layer, and a fleet
-serving several models materializes each network's constants once.
+net mapping: device buffers never touch the disk layer, and each
+network's constants materialize once per kernel set.
 
 Autotuner winners (:mod:`repro.tune`) persist through
 :func:`load_tuning` / :func:`store_tuning`, keyed on (net mapping,
@@ -411,15 +411,16 @@ def cached_plan(key: Tuple, compute: Callable[[], Any]) -> Any:
 
 
 def cached_constants(key: Tuple, compute: Callable[[], Any]) -> Any:
-    """Shared-constants handle cache (repro.exec.constants, ISSUE 7):
-    prepared plan constants — the shifted-weight device buffers every
-    tier of a plan ladder shares — keyed on the net mapping (plus the
-    resolved executors and the caller's kernel token).  In-memory ONLY:
-    the values are live device buffers, which have no business in the
-    pickled disk layer; a cold process re-materializes them once per
-    network (cheap next to plan compilation).  Bounded by its own small
-    LRU (`_constants_limit`): a handful of co-resident networks is the
-    design point, and each handle can hold a whole network's weights."""
+    """Shared-constants handle cache (repro.exec.constants): prepared
+    plan constants — the shifted-weight device buffers every tier of a
+    plan ladder shares — keyed on the net mapping (plus the resolved
+    executors and the kernels' ids).
+    In-memory ONLY: the values are live device buffers, which have no
+    business in the pickled disk layer; a cold process re-materializes
+    them once per network (cheap next to plan compilation).  Bounded by
+    its own small LRU (`_constants_limit`): a handful of co-resident
+    networks is the design point, and each handle can hold a whole
+    network's weights."""
     if not _enabled:
         return compute()
     try:
@@ -430,6 +431,18 @@ def cached_constants(key: Tuple, compute: Callable[[], Any]) -> Any:
     out = compute()
     _lru_put(_constants, key, out, _constants_limit, "const_evictions")
     return out
+
+
+def drop_constants(key: Tuple) -> None:
+    """Forget one :func:`cached_constants` entry, if it is held (the
+    implicit handles of repro.exec.constants go when their kernels do)."""
+    _constants.pop(key, None)
+
+
+def held_constants() -> list:
+    """The :func:`cached_constants` values held now (repro.exec.constants
+    counts their device bytes before it materializes another)."""
+    return list(_constants.values())
 
 
 def _tune_key(key: Tuple) -> Tuple:

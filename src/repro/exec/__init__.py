@@ -14,7 +14,8 @@ docstrings of exec/plan.py / exec/run.py.
                         mesh=mesh, batch=8)
     y = execute_plan(plan, kernels, x, mesh=mesh)
 """
-from .constants import PlanConstants, constant_counts, prepare_constants
+from .constants import (PlanConstants, constant_counts, fixed_constants,
+                        prepared_stats)
 from .glue import (ACTIVATIONS, GLUE_KINDS, GlueSpec, attention_stage,
                    center_crop, fit_spatial, layernorm, resolve_chain)
 from .memory import LayerMemory, network_memory, peak_bytes, total_bytes
@@ -32,7 +33,7 @@ __all__ = [
     "canonical_remat", "center_crop", "compile_counts", "compile_plan",
     "constant_counts", "donation_supported", "execute_layerwise",
     "execute_looped", "execute_oracle", "execute_plan", "fit_spatial",
-    "layernorm", "network_memory", "peak_bytes", "plan_segments",
-    "prepare_constants", "REASSOC_RTOL", "reassoc_error", "resolve_chain",
-    "total_bytes",
+    "fixed_constants", "layernorm", "network_memory", "peak_bytes", "plan_segments",
+    "prepared_stats", "REASSOC_RTOL", "reassoc_error",
+    "resolve_chain", "total_bytes",
 ]

@@ -13,7 +13,11 @@ indices) free to issue while this layer's cross-row `psum` drains.
 Without the barrier XLA hoists EVERY layer's kernel-derived tensors to
 the program start — all shifted weight matrices live at once — which
 measurably loses to the per-layer loop on deep concat stacks
-(benchmarks/plan_bench.py tracks both).  Inter-layer carry buffers live
+(benchmarks/plan_bench.py tracks both).  That in-program build runs only
+when the kernels are traced (or numpy): with fixed kernels the mapped
+layers read the network's prepared blocks as program inputs instead
+(exec/constants.py), built once per kernel set and shared by every
+tier.  Inter-layer carry buffers live
 inside the program (reused/donated by the compiler rather than
 round-tripping through host dispatch); Python-loop dispatch survives
 only *between* forwards — within one, nothing serializes on the host.
@@ -39,6 +43,7 @@ from repro.cnn.cim_conv import cim_conv2d_jit, cim_conv2d_traced
 from repro.cnn.mapped_net import mapped_conv2d_jit, mapped_conv2d_traced
 from repro.launch.device import compile_stats
 from repro.launch.tracing import GLUE, span
+from .constants import _prepare_blocks, fixed_constants, note_call
 from .glue import (ACTIVATIONS, attention_stage, center_crop, fit_spatial,
                    layernorm, stage_pool)
 from .plan import LayerPlan, NetworkPlan, mesh_axes
@@ -281,7 +286,7 @@ def _check_call(plan: NetworkPlan, kernels, x, mesh) -> None:
 
 def execute_plan(plan: NetworkPlan, kernels: Sequence[jnp.ndarray],
                  x: jnp.ndarray, *, mesh=None, activation=None,
-                 donate: bool = False, constants=None) -> jnp.ndarray:
+                 donate: bool = False) -> jnp.ndarray:
     """Run the planned forward as one jitted program.
 
     ``mesh`` must be the live mesh matching ``plan.mesh_axes`` (the Mesh
@@ -295,32 +300,29 @@ def execute_plan(plan: NetworkPlan, kernels: Sequence[jnp.ndarray],
     ignored when the platform the plan actually runs on — the mesh's
     devices when a mesh is bound, else the default backend
     (`donation_supported`) — does not implement donation (CPU).
-    ``constants`` is a shared `exec.constants.PlanConstants` handle for
-    this plan's network: its pre-materialized shifted-weight blocks feed
-    the mapped layers as program inputs, shared across every tier/ladder
-    of the network (``prepare_constants``).
+    Fixed kernels (concrete ``jax.Array``s, no trace active, one device)
+    feed the mapped layers the network's prepared shifted-weight blocks
+    as program inputs, shared across every tier/ladder of the network
+    (``exec.constants.fixed_constants``); traced or numpy kernels, and
+    plans on a mesh of several devices, build the blocks inside the
+    program.
 
     The call runs inside the host span ``repro.execute_plan`` (its
-    ``batch`` is the tier): checks, hashing the static plan, and the
-    jit dispatch.
+    ``batch`` is the tier, ``prepared`` whether the program reads
+    prepared blocks): checks, the handle's lookup, hashing the static
+    plan, and the jit dispatch.
     """
-    with span("execute_plan", batch=x.shape[0]):
+    with span("execute_plan", batch=x.shape[0]) as sp:
         _check_call(plan, kernels, x, mesh)
-        consts = None
-        if constants is not None:
-            if constants.net != plan.net:
-                raise ValueError("constants were prepared for a different "
-                                 "network mapping than this plan")
-            if constants.executors != plan.executors:
-                raise ValueError(
-                    f"constants were prepared for executors "
-                    f"{constants.executors}, plan resolved "
-                    f"{plan.executors}")
-            if len(constants.weights) != len(plan.layers):
-                raise ValueError(f"{len(constants.weights)} constant "
-                                 f"entries for {len(plan.layers)} planned "
-                                 f"layers")
-            consts = constants.weights
+        constants = fixed_constants(plan, kernels, mesh)
+        consts = None if constants is None else constants.weights
+        note_call(plan, consts is not None)
+        sp.set_metadata(prepared=consts is not None)
+        if consts is not None:
+            # a layer fed its blocks reads no kernel: keep it out of the
+            # program's inputs and the lookahead fence
+            kernels = [None if w is not None else k
+                       for k, w in zip(kernels, consts)]
         fn = _execute_jit_donated if donate and donation_supported(mesh) \
             else _execute_jit
         return fn(plan, tuple(kernels), x, consts, mesh=mesh,
@@ -329,10 +331,12 @@ def execute_plan(plan: NetworkPlan, kernels: Sequence[jnp.ndarray],
 
 def plan_compile_stats():
     """:func:`repro.launch.device.compile_stats` of the whole-forward
-    programs alone: what tracing, lowering and compiling (or loading)
-    every plan `execute_plan` has run cost this process."""
+    programs and the program that prepares their blocks: what tracing,
+    lowering and compiling (or loading) every plan `execute_plan` has
+    run cost this process."""
     return compile_stats(_execute_jit.__name__,
-                         _execute_jit_donated.__name__)
+                         _execute_jit_donated.__name__,
+                         _prepare_blocks.__name__)
 
 
 def execute_looped(plan: NetworkPlan, kernels: Sequence[jnp.ndarray],

@@ -16,10 +16,10 @@ fleet tier: a :class:`FleetScheduler` routes a *tagged* request stream
   arrival + max_delay) drains first, nearest deadline breaking ties;
   otherwise the model with the largest ``queued_rows x weight`` drains
   (keeping the arrays full), ties resolved by config order.
-* **plan-constant sharing** — co-resident ladders of the same network
-  reuse one prepared shifted-weight handle across all tiers
-  (`exec.constants.prepare_constants` through ``memo.cached_constants``)
-  instead of materializing the blocks once per tier.
+* **plan-constant sharing** — on one device every tier of a network's
+  ladder reads one prepared shifted-weight handle, as every
+  fixed-kernel ``execute_plan`` call does
+  (`exec.constants.fixed_constants`).
 
 Determinism invariant (regression-tested in tests/test_fleet.py):
 the scheduler core — routing, fairness, deadline override, tier
@@ -472,7 +472,6 @@ class FleetStats:
     models: Dict[str, ModelStats]
     wall_s: float
     warmup_steps: int
-    shared_constants: bool
 
     @property
     def request_images(self) -> int:
@@ -522,8 +521,7 @@ class FleetStats:
                  f" = {self.images_per_s:.1f} images/s "
                  f"({self.padded_images_per_s:.1f} padded), "
                  f"slo_attainment={self.slo_attainment:.3f}, "
-                 f"warmup_steps={self.warmup_steps}, "
-                 f"shared_constants={self.shared_constants}"]
+                 f"warmup_steps={self.warmup_steps}"]
         if self.delays_s:
             lines.append(
                 f"  all models pooled: queue-delay "
@@ -555,7 +553,6 @@ def serve_fleet(mappings: Mapping[str, object], config: FleetConfig,
                 trace: Sequence[TraceEvent], *, mesh=None,
                 policy="mapped", warmup: int = 1, seed: int = 0,
                 donate: Optional[bool] = None,
-                share_constants: bool = True,
                 lookahead: Optional[int] = None,
                 block: Optional[str] = None,
                 vmem_budget: Optional[int] = None,
@@ -574,17 +571,14 @@ def serve_fleet(mappings: Mapping[str, object], config: FleetConfig,
     many layers `chainable_prefix` cut before serving (surfaced in the
     stats rather than only printed).  Per model: a
     `batching.PlanLadder` (every tier compiled against the shared
-    ``mesh``) plus — with ``share_constants`` (default) — one
-    `exec.constants.PlanConstants` handle feeding every tier's program
-    its pre-materialized shifted-weight blocks
-    (`exec.constants.constant_counts` shows one materialization per
-    network, not per tier).  ``warmup`` forwards per tier run before
+    ``mesh``); ``execute_plan`` feeds every tier's program the
+    network's one prepared handle where the kernels are fixed
+    (`exec.constants.fixed_constants`).  ``warmup`` forwards per tier run before
     the clock starts; scheduling itself is :func:`run_fleet` on a
     :class:`FleetScheduler` (see the determinism invariant above)."""
     import jax
     import numpy as np
-    from repro.exec import (donation_supported, execute_plan,
-                            prepare_constants)
+    from repro.exec import donation_supported, execute_plan
     from .serve_cnn import _serving_kernels
     from .transformer import tokens_per_row
 
@@ -599,7 +593,6 @@ def serve_fleet(mappings: Mapping[str, object], config: FleetConfig,
     sched = FleetScheduler(config, mesh=mesh)
     ladders: Dict[str, batching.PlanLadder] = {}
     kernels: Dict[str, list] = {}
-    consts: Dict[str, object] = {}
     pools: Dict[str, np.ndarray] = {}
     shapes: Dict[str, tuple] = {}
     for spec in config.models:
@@ -610,13 +603,6 @@ def serve_fleet(mappings: Mapping[str, object], config: FleetConfig,
         ladders[spec.name] = ladder
         rng, ks = _serving_kernels(nm, seed)
         kernels[spec.name] = ks
-        if share_constants:
-            # keyed on (net mapping, executors, kernel token): every
-            # tier of every co-resident ladder of this network fetches
-            # the SAME handle out of memo.cached_constants
-            consts[spec.name] = prepare_constants(
-                ladder.plans[ladder.tiers[0]], ks,
-                token=("serve_fleet", seed))
         first = nm.layers[0].layer
         shapes[spec.name] = (first.ic, first.i_h, first.i_w)
         pools[spec.name] = rng.randn(
@@ -624,8 +610,7 @@ def serve_fleet(mappings: Mapping[str, object], config: FleetConfig,
 
     def run_tier(name: str, tier: int, x_np):
         y = execute_plan(ladders[name].plans[tier], kernels[name],
-                         jax.device_put(x_np), mesh=mesh, donate=donate,
-                         constants=consts.get(name))
+                         jax.device_put(x_np), mesh=mesh, donate=donate)
         return jax.block_until_ready(y)
 
     warmup_steps = 0
@@ -655,6 +640,5 @@ def serve_fleet(mappings: Mapping[str, object], config: FleetConfig,
                         execute=execute)
     wall = clock() - t0
     return (FleetStats(models=stats, wall_s=wall,
-                       warmup_steps=warmup_steps,
-                       shared_constants=share_constants),
+                       warmup_steps=warmup_steps),
             records)

@@ -29,7 +29,7 @@ Four serving modes:
   share ONE serving mesh under mixed Poisson traffic: per-model
   coalescers + plan ladders behind a cross-model drain policy, with
   prepared shifted-weight constants shared across each network's tiers
-  (`launch/fleet.py`); per-model and aggregate effective vs padded
+  on one device (`launch/fleet.py`); per-model and aggregate effective vs padded
   images/s, queue-delay percentiles, and SLO attainment are reported.
 * **replicas** (``--replicas N``) — process-level scale-out
   (`launch/replica.py`; DESIGN.md §12): N worker processes, each with
@@ -392,7 +392,6 @@ def _print_fleet(stats, *, tag: str, max_batch: int, max_delay_ms: float,
           f"slo_attainment={stats.slo_attainment:.3f};mesh={tag};"
           f"max_batch={max_batch};max_delay_ms={max_delay_ms};"
           f"warmup_steps={stats.warmup_steps};"
-          f"shared_constants={stats.shared_constants};"
           f"table_builds={st['table_misses']};disk_hits={st['disk_hits']}")
 
 
@@ -454,7 +453,6 @@ def _main_fleet(args) -> None:
         mappings, config, trace, mesh=mesh, policy=args.policy,
         warmup=args.warmup, seed=args.seed,
         donate=False if args.no_donate else None,
-        share_constants=not args.no_share_constants,
         dropped_layers=dropped)
     _print_fleet(stats, tag=tag, max_batch=max_batch,
                  max_delay_ms=max_delay_ms, st=st)
@@ -618,9 +616,6 @@ def main(argv=None) -> None:
     flt.add_argument("--slo-ms", type=float, default=None,
                      help="per-request queue-delay SLO target for "
                           "attainment reporting (fleet mode)")
-    flt.add_argument("--no-share-constants", action="store_true",
-                     help="materialize shifted-weight constants per "
-                          "tier instead of once per network")
     args = ap.parse_args(argv)
 
     if args.cache_dir is not None:

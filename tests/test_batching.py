@@ -476,7 +476,7 @@ def test_fleet_stats_pooled_percentiles_match_numpy():
     mb.tiers[2] = TierStats(plan_batch=2)
     mb.tiers[2].delays_s = [0.001, 0.002, 0.500, 0.600]
     fs = FleetStats(models={"a": ma, "b": mb}, wall_s=1.0,
-                    warmup_steps=0, shared_constants=True)
+                    warmup_steps=0)
     pooled = ma.tiers[1].delays_s + mb.tiers[2].delays_s
     for q in (50, 95, 99):
         expect = float(np.percentile(pooled, q,

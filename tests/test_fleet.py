@@ -325,21 +325,25 @@ def test_mixed_poisson_trace_shape_and_chainable_prefix():
 # ---------------------------------------------------------------------------
 
 def test_constants_materialize_once_per_network_not_per_tier():
-    """The shared-constants handle comes out of memo.cached_constants:
-    every tier of the ladder gets the SAME PlanConstants object, the
-    per-key counters show ONE materialization for the network, and
-    outputs with the handle are bit-identical to the in-trace build."""
+    """The prepared handle comes out of memo.cached_constants under the
+    kernels' ids: every tier of the ladder gets the SAME PlanConstants
+    object, the per-key counters show ONE materialization for the
+    network, and outputs with the handle are bit-identical to the
+    in-trace build."""
+    import functools
+
+    import jax
     import jax.numpy as jnp
     import numpy as np
-    from repro.exec import (constant_counts, execute_plan,
-                            prepare_constants)
+    from repro.exec import (compile_plan, constant_counts, execute_plan,
+                            fixed_constants)
     from repro.launch.serve_cnn import _serving_kernels
     memo.clear()
     net = _small_net()
     ladder = batching.PlanLadder(net, (1, 2))
     rng, ks = _serving_kernels(net, 0)
-    c1 = prepare_constants(ladder.plans[1], ks, token=("fleet", 0))
-    c2 = prepare_constants(ladder.plans[2], ks, token=("fleet", 0))
+    c1 = fixed_constants(ladder.plans[1], ks)
+    c2 = fixed_constants(ladder.plans[2], ks)
     assert c1 is c2                      # one handle across tiers
     counts = constant_counts(net=net)
     assert len(counts) == 1 and list(counts.values()) == [1]
@@ -349,32 +353,38 @@ def test_constants_materialize_once_per_network_not_per_tier():
     for t in ladder.tiers:
         x = jnp.asarray(rng.randn(t, first.ic, first.i_h, first.i_w),
                         jnp.float32)
-        y_off = execute_plan(ladder.plans[t], ks, x)
-        y_on = execute_plan(ladder.plans[t], ks, x, constants=c1)
+        # through jit the kernels are tracers: the in-trace build
+        y_off = jax.jit(functools.partial(execute_plan,
+                                          ladder.plans[t]))(ks, x)
+        y_on = execute_plan(ladder.plans[t], ks, x)
         assert bool(jnp.all(y_on == y_off))
-    # a different kernel token materializes separately; token=None is
-    # an unshared handle and never touches the cache/counters
-    c3 = prepare_constants(ladder.plans[1], ks, token=("fleet", 1))
+    assert fixed_constants(ladder.plans[1], ks) is c1
+    assert list(constant_counts(net=net).values()) == [1]
+    # other kernel objects materialize separately; numpy kernels never
+    # touch the cache/counters
+    ks2 = [k + 0 for k in ks]
+    c3 = fixed_constants(ladder.plans[1], ks2)
     assert c3 is not c1
     assert sum(constant_counts(net=net).values()) == 2
-    c4 = prepare_constants(ladder.plans[1], ks)
-    assert c4 is not c1
+    assert fixed_constants(ladder.plans[1],
+                           [np.asarray(k) for k in ks]) is None
     assert sum(constant_counts(net=net).values()) == 2
-    # handles validate against the plan they are fed to
+    # the handle is keyed on the network mapping: another network's
+    # plan gets its own
     other = _small_net(3)
-    from repro.exec import compile_plan
     plan_o = compile_plan(other, executor_policy="mapped", batch=1)
-    x1 = jnp.asarray(np.zeros((1, first.ic, first.i_h, first.i_w),
-                              np.float32))
-    with pytest.raises(ValueError, match="different network"):
-        execute_plan(plan_o, _serving_kernels(other, 0)[1], x1,
-                     constants=c1)
+    ks_o = _serving_kernels(other, 0)[1]
+    c_o = fixed_constants(plan_o, ks_o)
+    assert c_o is not c1 and len(c_o.weights) == len(other.layers)
+    assert len(constant_counts(net=other)) == 1
 
 
 def test_fleet_schedule_identical_with_and_without_sharing():
     """Constant sharing is a pure execution-side optimization: under the
     virtual clock the drain/launch schedule and per-model stats are
-    identical with sharing on and off."""
+    identical whether the tiers read prepared blocks or, with the memo
+    layer off, build them in the program."""
+    from repro.exec import prepared_stats
     from repro.launch.fleet import serve_fleet
     net = _small_net()
     maps = {"a": net, "b": _small_net(3)}
@@ -383,16 +393,22 @@ def test_fleet_schedule_identical_with_and_without_sharing():
         ModelSpec("b", max_batch=2, max_delay_s=0.001, slo_ms=100.0)))
     trace = mixed_poisson_trace(["a", "b"], 8, 300.0, 2, seed=5)
 
-    def run(share):
+    def run():
         clk = _VClock()
-        return serve_fleet(maps, cfg, trace, warmup=1,
-                           share_constants=share, clock=clk,
+        return serve_fleet(maps, cfg, trace, warmup=1, clock=clk,
                            sleep=clk.sleep)
 
-    s_on, r_on = run(True)
-    s_off, r_off = run(False)
+    memo.clear()
+    s_on, r_on = run()
+    on = prepared_stats()
+    with memo.disabled():
+        s_off, r_off = run()
+    off = prepared_stats()
     assert r_on == r_off
-    assert s_on.shared_constants and not s_off.shared_constants
+    assert on["prepared_calls"] > 0 and on["in_trace_calls"] == 0
+    assert on["materializations"] == 2          # one per network
+    assert off["prepared_calls"] == on["prepared_calls"]
+    assert off["in_trace_calls"] == on["prepared_calls"]
     for m in ("a", "b"):
         assert (s_on.models[m].request_images
                 == s_off.models[m].request_images)
@@ -402,9 +418,11 @@ def test_fleet_schedule_identical_with_and_without_sharing():
 
 def test_constants_shared_across_tiers_forced_multi_device():
     """Forced-8-device case (pattern from tests/test_serve_cnn.py):
-    with a data=2 serving mesh, outputs are bit-identical with sharing
-    on vs off on every tier, and the counters show constants
-    materialized once per network, not once per tier."""
+    with a data=2 serving mesh the fixed kernels keep the in-trace
+    build, which places the blocks where the mesh shards them: nothing
+    is materialized on any tier, outputs are bit-identical to the
+    traced call, and a one-device run of the same tiers shares one
+    handle across them."""
     import os
     import subprocess
     import sys
@@ -413,7 +431,7 @@ import numpy as np, jax, jax.numpy as jnp
 from repro.core import ArrayConfig, MacroGrid, map_net, networks
 from repro.cnn.mapped_net import zero_pruned_kernels
 from repro.exec import (compile_plan, constant_counts, execute_plan,
-                        prepare_constants)
+                        fixed_constants, prepared_stats)
 from repro.launch.mesh import serving_mesh_for
 assert len(jax.devices()) == 8
 net = map_net("cnn8", networks.cnn8()[:3], ArrayConfig(64, 64),
@@ -427,19 +445,22 @@ ks = zero_pruned_kernels(net, [
     jnp.asarray(rng.randn(m.layer.k_h, m.layer.k_w,
                           m.layer.ic // m.group, m.layer.oc) * 0.2,
                 jnp.float32) for m in net.layers])
-handles = [prepare_constants(plans[t], ks, token=("fleet", 0))
-           for t in (2, 4)]
-assert handles[0] is handles[1], "tiers got distinct handles"
-counts = constant_counts(net=net)
-assert len(counts) == 1 and list(counts.values()) == [1], counts
+assert all(fixed_constants(plans[t], ks, mesh) is None for t in (2, 4))
 first = net.layers[0].layer
 for t in (2, 4):
     x = jnp.asarray(rng.randn(t, first.ic, first.i_h, first.i_w),
                     jnp.float32)
-    y_off = execute_plan(plans[t], ks, x, mesh=mesh)
-    y_on = execute_plan(plans[t], ks, x, mesh=mesh,
-                        constants=handles[0])
+    # through jit the kernels are tracers: the in-trace build
+    y_off = jax.jit(lambda ks, x: execute_plan(plans[t], ks, x,
+                                               mesh=mesh))(ks, x)
+    y_on = execute_plan(plans[t], ks, x, mesh=mesh)
     assert bool(jnp.all(y_on == y_off)), f"tier {t} outputs drifted"
+assert constant_counts(net=net) == {}, constant_counts(net=net)
+assert prepared_stats()["in_trace_calls"] == 4
+one = {t: compile_plan(net, executor_policy="mapped", batch=t)
+       for t in (2, 4)}
+handles = [fixed_constants(one[t], ks) for t in (2, 4)]
+assert handles[0] is handles[1], "tiers got distinct handles"
 assert list(constant_counts(net=net).values()) == [1]
 print("FLEET-CONSTS-OK")
 """
@@ -481,7 +502,7 @@ def test_fleet_bench_shared_beats_dedicated_slices():
 @pytest.mark.slow
 def test_fleet_cli_smoke(capsys):
     """serve_cnn --fleet end to end: per-model + aggregate CSV rows with
-    SLO attainment, constants shared by default."""
+    SLO attainment."""
     from repro.launch import serve_cnn
     serve_cnn.main(["--fleet", "cnn8,inception", "--batch", "2",
                     "--requests", "8", "--arrival-rate", "200",
@@ -495,6 +516,5 @@ def test_fleet_cli_smoke(capsys):
     kv = _parse_kv(agg)
     assert kv["models"] == "cnn8/inception"
     assert float(kv["images_per_s"]) > 0
-    assert kv["shared_constants"] == "True"
     assert 0.0 <= float(kv["slo_attainment"]) <= 1.0
     assert "chainable prefix" in out     # inception is a layer set
